@@ -135,6 +135,9 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_dataset.json")
     args = ap.parse_args()
 
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
+
     apps = (args.apps.split(",") if args.apps
             else ["sobel", "gaussian"] if args.smoke
             else ["sobel", "gaussian", "kmeans"])

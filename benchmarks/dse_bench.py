@@ -399,6 +399,9 @@ def main() -> None:
     ap.add_argument("--ckpt-gate-pct", type=float, default=5.0)
     ap.add_argument("--fault-out", default="BENCH_fault.json")
     args = ap.parse_args()
+
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
     mode = "smoke" if args.smoke else args.mode
     smoke = mode == "smoke"
 

@@ -138,9 +138,8 @@ def main() -> None:
     from repro.core.artifacts import enable_compilation_cache
     from repro.core.engine import SurrogateEngine
 
-    # Persistent XLA compilation cache: setup_s is dominated by
-    # recompilation of shapes traced on every previous run, so warm runs
-    # on the same host skip straight to execution.
+    # persistent compilation cache: warm runs skip the recompilation that
+    # dominates setup_s
     cache_dir = enable_compilation_cache()
 
     n_samples, epochs = (160, 6) if smoke else (600, 25)
@@ -214,11 +213,12 @@ def main() -> None:
           f"off_cps={serial_cps:.1f},"
           f"gain={batched_cps / serial_cps:.2f}x")
 
-    # -- sharded drain: config axis spread over every local device ---------
+    # -- multi-device drain: chunks spread over every local device --------
     engine_sharded = SurrogateEngine.from_gnn(two_cfg, params, ds, app,
                                               entries, chunk_size=args.chunk,
                                               devices=0)
-    engine_sharded(configs[:args.chunk])   # compile the sharded chunk shape
+    # compile the chunk shape on every device (one chunk each)
+    engine_sharded(configs[:args.chunk * engine_sharded.devices])
 
     def sharded_cold():
         engine_sharded.clear_cache()
@@ -228,8 +228,8 @@ def main() -> None:
     sharded_rows, sharded_s = best_of(sharded_cold)
     sharded_cps = len(configs) / sharded_s
     sharded_speedup = sharded_cps / batched_cps
-    # acceptance: sharding is invisible in values — bit-identical, not
-    # merely allclose (zero-communication leading-axis split)
+    # acceptance: spreading chunks is invisible in values — bit-identical,
+    # not merely allclose (each device runs the single-device program)
     sharded_identical = bool(np.array_equal(batched_rows, sharded_rows))
     print(f"engine_bench,sharded,devices={engine_sharded.devices},"
           f"configs_per_sec={sharded_cps:.1f},"

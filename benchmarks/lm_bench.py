@@ -44,16 +44,6 @@ def bench_kernels():
                q, k, v)
     print(f"kernel,flash_attention_ref,{us:.0f}us_per_call,shape=1x4x256x32")
 
-    from repro.accel import library as lib
-    e = lib.build_library("mul8")[5]
-    lut = ops.build_lut(e.inst.fn(), 8, 8)
-    a = jnp.asarray(rng.integers(0, 256, 1 << 16), jnp.int32)
-    bb = jnp.asarray(rng.integers(0, 256, 1 << 16), jnp.int32)
-    us = _time(jax.jit(lambda *x: ops.lut_eval(*x, wb=8, backend="ref")),
-               lut, a, bb)
-    print(f"kernel,lut_eval_ref,{us:.0f}us_per_call,"
-          f"melem_s={(1 << 16) / us:.1f}")
-
     aa = jnp.asarray(rng.random((512, 64)) * .9, jnp.float32)
     bbb = jnp.asarray(rng.standard_normal((512, 64)), jnp.float32)
     y0 = jnp.zeros(64, jnp.float32)

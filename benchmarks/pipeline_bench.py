@@ -50,6 +50,9 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_pipeline.json")
     args = ap.parse_args()
 
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
+
     from repro.core import pipeline as P
     from repro.core.artifacts import ArtifactStore
 

@@ -49,6 +49,9 @@ def main() -> None:
                                           "train,pipeline,serve,fault")
     args = ap.parse_args()
 
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
+
     from benchmarks import paper_tables as T
     from benchmarks import lm_bench as L
 

@@ -163,13 +163,9 @@ def gnn_tenant_bench(app: str, n_requests: int = 16):
     check parity against the one-shot `run_staged` engine (shared store
     => same memoized engine object => bit-identical)."""
     from repro.core import pipeline as P
-    from repro.core.artifacts import ArtifactStore, enable_compilation_cache
+    from repro.core.artifacts import ArtifactStore
     from repro.launch.serve import EvalService, ServeRequest
 
-    # An in-memory store has no root to hang the XLA cache off, so wire
-    # the host-default persistent cache explicitly: warm re-runs skip the
-    # recompilation that dominates pipeline_s.
-    enable_compilation_cache()
     cfg = P.PipelineConfig(app=app, n_samples=120, epochs=4,
                            dse_budget=100, hidden=32, n_layers=2,
                            dse_pop=16)
@@ -245,6 +241,9 @@ def main() -> None:
     ap.add_argument("--dispatch-ms", type=float, default=3.0)
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
     mode = "smoke" if args.smoke else args.mode
     smoke = mode == "smoke"
 

@@ -105,6 +105,9 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_train.json")
     args = ap.parse_args()
 
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
+
     import jax
     from repro.accel import apps as apps_lib
     from repro.core import dataset as ds_lib

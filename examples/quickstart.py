@@ -24,6 +24,9 @@ def main():
                          "(docs/pipeline_stages.md)")
     args = ap.parse_args()
 
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
+
     cfg = (P.PipelineConfig.paper_faithful(args.app) if args.paper
            else P.PipelineConfig(app=args.app, n_samples=800, epochs=30,
                                  dse_budget=1500, hidden=96, n_layers=4))

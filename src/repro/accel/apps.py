@@ -442,8 +442,7 @@ def probe_scalar(app: AccelDef, choice: Dict[str, lib.LibEntry]
 #   * multipliers and sqrt (the transcendental-heavy families: mitchell,
 #     drum, pwl, newton) go through stacked LUT truth tables
 #     (`library.stacked_lut`) with the per-config library choice folded
-#     into the table index — dispatched to the Pallas `kernels.lut_eval`
-#     kernel on TPU, a pure-JAX gather elsewhere;
+#     into the table index, read by one XLA gather on every platform;
 #   * adders/subtractors, whose widened truth tables would need 2^24-2^32
 #     entries, are evaluated analytically with the family id and cut
 #     parameter as traced per-config scalars (`units.addsub_batched`);
@@ -456,6 +455,13 @@ class LutDomainError(RuntimeError):
     """An app drove a LUT-tabulated unit outside its table domain."""
 
 
+def lut_gather(table: jax.Array, a: jax.Array, b: jax.Array,
+               wb: int) -> jax.Array:
+    """Read a truth table at ``(a << wb) | b``: one XLA gather (the
+    labeler's LUT path on every platform)."""
+    return jnp.take(table, (a << wb) | b, axis=0)
+
+
 def _entries_items(app: AccelDef, entries: Dict[str, Sequence]
                    ) -> Tuple[Tuple[str, Tuple[lib.LibEntry, ...]], ...]:
     """Hashable (kind, entries) signature restricted to the app's kinds."""
@@ -464,7 +470,7 @@ def _entries_items(app: AccelDef, entries: Dict[str, Sequence]
 
 
 @functools.lru_cache(maxsize=64)
-def _batch_label_fn(app_name: str, entries_items, backend: str):
+def _batch_label_fn(app_name: str, entries_items):
     """Compiled labeler: (C (B,U) int32, images, exact_out) -> ((B,) ssim,
     guard dict); two jitted stages (vmapped functional model, vmapped
     SSIM). `guard_meta` maps guard tags to LUT domains; it is filled at
@@ -489,12 +495,6 @@ def _batch_label_fn(app_name: str, entries_items, backend: str):
     def _lut_impl(node, kind, ea, eb, table, e, guards, counts):
         unary = kind.op == "sqrt"
 
-        def gather(tab, af, bf, wb):
-            if backend == "pallas":
-                from repro.kernels import ops as kernel_ops
-                return kernel_ops.lut_eval(tab, af, bf, wb)
-            return jnp.take(tab, (af << wb) | bf, axis=0)
-
         def excess(x, bits):
             # >0 iff x leaves [0, 2^bits), by how much; ONE reduction per
             # operand (reductions are costly here: a consuming reduction
@@ -509,8 +509,8 @@ def _batch_label_fn(app_name: str, entries_items, backend: str):
             if unary:
                 guards[tag] = (excess(a, ea), zero)
                 af = ((e << ea) | a).reshape(-1)
-                return gather(table, af, jnp.zeros_like(af), 0
-                              ).reshape(a.shape)
+                return lut_gather(table, af, jnp.zeros_like(af), 0
+                                  ).reshape(a.shape)
             const_b = None
             if not isinstance(b, jax.core.Tracer):
                 vals = np.unique(np.asarray(b))
@@ -524,10 +524,10 @@ def _batch_label_fn(app_name: str, entries_items, backend: str):
                 # against a 2^ea-per-entry table that lives in cache
                 guards[tag] = (excess(a, ea), zero)
                 sub = table.reshape(-1, 1 << eb)[:, const_b]
-                out = gather(sub, af, jnp.zeros_like(af), 0)
+                out = lut_gather(sub, af, jnp.zeros_like(af), 0)
             else:
                 guards[tag] = (excess(a, ea), excess(b, eb))
-                out = gather(table, af, b.reshape(-1), eb)
+                out = lut_gather(table, af, b.reshape(-1), eb)
             return out.reshape(a.shape)
 
         return impl
@@ -586,24 +586,18 @@ def _check_lut_guards(app: AccelDef, guard_meta, guards) -> None:
 def accuracy_ssim_batch(app: AccelDef, entries: Dict[str, Sequence],
                         configs, images: jax.Array,
                         exact_out: jax.Array | None = None, *,
-                        chunk: int = 256, backend: str = "auto"
-                        ) -> np.ndarray:
+                        chunk: int = 256) -> np.ndarray:
     """SSIM labels for a batch of configurations: (B,) float64.
 
     ``configs`` is a (B, n_units) int block of library-entry indices (the
     `dataset.sample_configs` layout). Images are evaluated through the
     config-batched functional model in fixed-size chunks (the ragged tail
     padded with a repeated row and sliced, so the jit cache holds one
-    shape). ``backend="auto"`` uses the Pallas LUT kernel on TPU and the
-    pure-JAX gather elsewhere; "pallas"/"jnp" force a path.
+    shape).
     """
-    if backend == "auto":
-        from repro.kernels import ops as kernel_ops
-        backend = "pallas" if kernel_ops.ON_TPU else "jnp"
     if exact_out is None:
         exact_out = app.run(make_impls(app, exact_choice(app)), images)
-    fn, guard_meta = _batch_label_fn(app.name, _entries_items(app, entries),
-                                     backend)
+    fn, guard_meta = _batch_label_fn(app.name, _entries_items(app, entries))
     C = np.asarray(configs, np.int32).reshape(len(configs), -1)
     B = C.shape[0]
     out = np.empty(B, np.float64)

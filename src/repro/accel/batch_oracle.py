@@ -295,13 +295,13 @@ def crit_sets(rep: Dict[str, np.ndarray]) -> List[set]:
 
 
 def label_configs(app: apps_lib.AccelDef, entries: Dict[str, Sequence],
-                  configs, images, exact_out=None, *, chunk: int = 256,
-                  backend: str = "auto") -> Dict[str, np.ndarray]:
+                  configs, images, exact_out=None, *, chunk: int = 256
+                  ) -> Dict[str, np.ndarray]:
     """Complete batched label rows: synthesis PPA/critical bits + SSIM."""
     C = np.asarray(configs, np.int64).reshape(len(configs), -1)
     rep = synthesize_batch(app, entries, C)
     rep["ssim"] = apps_lib.accuracy_ssim_batch(
-        app, entries, C, images, exact_out, chunk=chunk, backend=backend)
+        app, entries, C, images, exact_out, chunk=chunk)
     return rep
 
 

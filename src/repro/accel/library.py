@@ -325,7 +325,7 @@ def stacked_lut(entries: Tuple[LibEntry, ...], ea: int, eb: int
     Entry ``i``'s value for operands (a, b) sits at index
     ``(i << (ea+eb)) | (a << eb) | b``, so folding the per-config library
     choice into the ``a`` operand ``(i << ea) | a`` turns a whole batch of
-    mixed configurations into one gather through `kernels.lut_eval`.
+    mixed configurations into one gather.
     """
     return np.concatenate(
         [np.asarray(e.inst.lut(ea, eb)) for e in entries])

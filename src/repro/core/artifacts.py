@@ -108,41 +108,38 @@ class StoreStats:
                     "quarantines": list(self.quarantines)}
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None
-                             ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at `cache_dir`.
+# the checkout root (src/repro/core/artifacts.py -> three levels up)
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-    The pipeline compiles the same handful of shapes every process
-    (bucketed engine chunks, the training scan) — BENCH_engine.json's
-    ``setup_s`` was ~60s of recompilation per bench run before this.
-    With the cache enabled, XLA executables persist across processes and
-    a warm run skips straight to execution.
 
-    Idempotent and best-effort: returns the cache directory on success,
-    None when the running JAX build rejects the config (older/headless
-    builds) — callers treat None as "no cache, proceed cold". The min
-    compile-time/entry-size thresholds are zeroed so even the small CPU
-    executables of the test/bench suite are cached. Called automatically
-    by `ArtifactStore` for on-disk stores (subdir ``xla_cache``) and by
-    the benches' setup; a shared default directory under the system temp
-    dir serves ad-hoc use.
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    The pipeline compiles the same handful of shapes in every process
+    (bucketed engine chunks, the training scan, the labeling model); with
+    the cache on, a later process loads those executables instead of
+    recompiling them. Each entry point (``chip_smoke.py``, the benchmarks,
+    ``examples/quickstart.py``, ``repro.launch.serve``) calls this once at
+    start-up; library code never does.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets no other directory. Otherwise the cache lives at one fixed
+    path inside the checkout, `DEFAULT_COMPILE_CACHE` (git-ignored): a
+    directory whose name changed from run to run would never hit. The
+    min compile-time / entry-size thresholds are zeroed so the small
+    executables of this repo are cached too. Errors propagate: a cache
+    that cannot be set up is a failure, not a silent cold run.
     """
-    try:
-        import jax
+    import jax
 
-        path = Path(cache_dir) if cache_dir else \
-            Path(tempfile.gettempdir()) / "approxpilot-xla-cache"
-        path.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", 0)
-        except Exception:
-            pass           # knob absent on this jax version: fine
-        return str(path)
-    except Exception:
-        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(DEFAULT_COMPILE_CACHE)
+        Path(path).mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def _to_numpy_tree(obj: Any) -> Any:
@@ -171,15 +168,8 @@ class ArtifactStore:
 
     def __init__(self, root: Optional[str] = None):
         self.root = Path(root) if root is not None else None
-        self.compilation_cache_dir: Optional[str] = None
         if self.root is not None:
             self.root.mkdir(parents=True, exist_ok=True)
-            # a persistent store means a resumable workflow: co-locate
-            # JAX's persistent compilation cache so the jit setup cost
-            # (recompiling the same bucketed shapes every process) is
-            # paid once per store, not once per run
-            self.compilation_cache_dir = enable_compilation_cache(
-                str(self.root / "xla_cache"))
         self._memory: Dict[str, Any] = {}
         # last-write wall-clock timestamp per memory-tier key (same time
         # domain as disk mtimes), for `gc_checkpoints`; disk-only entries

@@ -16,12 +16,12 @@ this module provides the production implementation of that callable:
       power-of-two bucket, so the jit cache holds at most
       ``log2(chunk_size) + 1`` compiled shapes no matter how ragged the
       incoming batches are;
-    * **device-sharded dispatch** — with ``devices > 1`` the GNN paths
-      split every chunk along the config axis over the host's devices
-      (`repro.distributed.meshes.shard_leading_axis`): per-row compute is
-      fully independent, so the sharded wave is bit-identical to the
-      single-device one (proven by tests/test_engine_sharded.py the same
-      way test_islands_batched.py proves fleet identity);
+    * **multi-device dispatch** — with ``devices > 1`` the GNN paths
+      place successive chunks on the host's devices in turn
+      (`_over_devices`). A chunk is never split: each device runs the
+      single-device program at the single-device shape, so every row is
+      bit-identical to the ``devices=1`` engine
+      (tests/test_engine_sharded.py);
     * **featurize/compute overlap** — the GNN backends are
       `PipelinedBackend`s (prepare → dispatch → collect); with ≥ 2 chunks
       a worker thread featurizes chunk *k+1* on the host (the schema-v2
@@ -32,10 +32,11 @@ this module provides the production implementation of that callable:
     * **config-key memoization** — NSGA-II/III re-evaluations of surviving
       parents (and the stagnation-restart re-injections) are free across
       generations; duplicates inside a single batch are evaluated once;
-    * **Pallas kernel dispatch** — the GNN path runs its message-passing
-      layers through the fused `repro.kernels.gnn_mp` kernel when available
-      (native on TPU, ``interpret=True`` elsewhere) with a parity check at
-      construction and a transparent pure-JAX fallback;
+    * **Pallas kernel dispatch** — on TPU the gcn/gsae GNN paths run
+      their message-passing layers through the fused
+      `repro.kernels.gnn_mp` kernel, held at construction to the pure-JAX
+      forward by a parity check that raises on failure (there is no
+      silent fallback; ``interpret=True`` off-TPU when forced);
     * **per-call stats** — configs/sec, cache hit rate, chunk/padding
       counts (`EngineStats`), surfaced into ``PipelineResult.metrics``.
 
@@ -276,14 +277,12 @@ class PipelinedBackend:
       start compute. Under JAX async dispatch the jitted call returns
       immediately with a future-like device array, so the engine can keep
       dispatching while earlier chunks execute. With ``devices > 1`` the
-      GNN constructors shard X's leading (config) axis here.
+      GNN constructors put each chunk on the next device here.
     * ``collect(handle) -> (B, n_obj) ndarray`` — block on the device
       result, transfer, and post-process (denormalize, ssim flip).
 
-    ``devices`` records the shard width for `EngineStats`; it is a cap —
-    the actual mesh per chunk is the largest device prefix dividing that
-    chunk's length (`meshes.shard_leading_axis`), which for power-of-two
-    buckets is the full cap whenever the cap is a power of two.
+    ``devices`` records, for `EngineStats`, how many devices the chunks
+    are spread over.
     """
 
     def __init__(self, prepare: Callable[[Sequence[Config]], Any],
@@ -318,20 +317,45 @@ def _resolve_devices(devices) -> int:
     return max(1, min(n, len(jax.devices())))
 
 
-def _maybe_shard(X, n_devices: int):
-    """Shard X's leading (config) axis over up to `n_devices` devices;
-    identity when the cap is 1 (single-device engines never touch the
-    mesh machinery)."""
+def _over_devices(fn, n_devices: int):
+    """``fn`` with successive calls placed on up to `n_devices` local
+    devices in turn; ``fn`` itself when the cap is 1 (single-device
+    engines never touch device placement).
+
+    A call's rows stay together on one device, which runs the
+    single-device program at the single-device shape. Splitting a chunk
+    instead would change its rows: their last bits depend on the program
+    XLA compiles around them (on a TPU the pure-JAX forward's node-axis
+    sums give different bits in a 128-row and a 512-row call, and a
+    sharded program differs again). The pipelined engine dispatches every
+    chunk of a wave before collecting any, so a multi-chunk wave keeps
+    all the devices busy."""
     if n_devices <= 1:
-        return X
-    from repro.distributed import meshes
-    return meshes.shard_leading_axis(X, int(X.shape[0]),
-                                     max_devices=n_devices)
+        return fn
+    import jax
+
+    devices = itertools.cycle(jax.devices()[:n_devices])
+    lock = threading.Lock()
+
+    def call(X):
+        with lock:
+            device = next(devices)
+        return fn(jax.device_put(X, device))
+    return call
 
 
 # --------------------------------------------------------------------------
 # GNN predict functions (pure-JAX and Pallas-kernel paths)
 # --------------------------------------------------------------------------
+#
+# Both forwards run their contractions at ``Precision.HIGHEST`` (float32 on
+# the TPU MXU; XLA's default there is one bfloat16 pass). They then differ
+# only in summation order, which over the paper's widest surrogate (hidden
+# 300, 5 layers per stage) moves a normalized output by far less than
+# KERNEL_PARITY_TOL; a bfloat16 gap would not.
+
+KERNEL_PARITY_TOL = 1e-4     # rtol and atol, on normalized targets
+
 
 def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
                       mask_row: np.ndarray):
@@ -348,7 +372,8 @@ def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
         B = X.shape[0]
         adj = jnp.broadcast_to(A, (B,) + A.shape)
         mask = jnp.broadcast_to(m, (B,) + m.shape)
-        return models.predict(two_cfg, params, adj, X, mask)[0]
+        with jax.default_matmul_precision("highest"):
+            return models.predict(two_cfg, params, adj, X, mask)[0]
 
     return f
 
@@ -401,8 +426,7 @@ def _make_kernel_predict(two_cfg, params, adj_row: np.ndarray,
     A1 = scaled_adj(s1)
     m_row = jnp.asarray(mask_row)
 
-    @jax.jit
-    def f(X):
+    def forward(X):
         B = X.shape[0]
         adj_k = jnp.broadcast_to(A1, (B,) + A1.shape)
         mask = jnp.broadcast_to(m_row, (B,) + m_row.shape)
@@ -415,6 +439,11 @@ def _make_kernel_predict(two_cfg, params, adj_row: np.ndarray,
         x2 = X.at[..., crit_idx].set(bit * mask)
         h2 = stack(s2, params.stage2, adj_k, x2, mask)
         return readout(s2, params.stage2, h2, mask)
+
+    @jax.jit
+    def f(X):
+        with jax.default_matmul_precision("highest"):
+            return forward(X)
 
     return f
 
@@ -931,8 +960,8 @@ class SurrogateEngine:
     def from_gnn(cls, two_cfg, params, ds, app,
                  entries: Dict[str, Sequence], *, chunk_size: int = 512,
                  use_kernel: str = "auto", cache: bool = True,
-                 devices: int = 1, overlap: Optional[bool] = None,
-                 parity_atol: float = 2e-3) -> "SurrogateEngine":
+                 devices: int = 1, overlap: Optional[bool] = None
+                 ) -> "SurrogateEngine":
         """GNN-surrogate engine (the ApproxPilot fast path).
 
         Featurizes by table lookup, runs the two-stage model under jit with
@@ -942,21 +971,19 @@ class SurrogateEngine:
         featurization with device compute by default (``overlap``, see
         `SurrogateEngine` — disableable for measurement).
 
-        ``devices``: shard each chunk's config axis over up to this many
-        local devices (``0`` = all of them) via
-        `meshes.shard_leading_axis` — per-row compute is independent, so
-        results are bit-identical to ``devices=1`` at any width
-        (tests/test_engine_sharded.py). Power-of-two chunk buckets divide
-        evenly over power-of-two device counts, so sharding never forces
-        a fallback to replication on the fixed-shape path.
+        ``devices``: spread chunks over up to this many local devices
+        (``0`` = all of them), one whole chunk per device in turn
+        (`_over_devices`); rows are bit-identical to ``devices=1`` at any
+        width (tests/test_engine_sharded.py).
 
-        ``use_kernel``: "auto" dispatches to the Pallas `gnn_mp` kernel on
-        TPU for the gcn/gsae architectures, transparently falling back to
-        pure JAX when the kernel fails to build or fails the probe-batch
-        parity check; "on" *forces* the kernel path (interpret-mode
-        off-TPU — correct but slow, used by tests) and raises on an
-        unsupported arch, a build error, or a parity mismatch; "off"
-        forces pure JAX.
+        ``use_kernel``: "auto" runs the message-passing layers through the
+        Pallas `gnn_mp` kernel on TPU for the gcn/gsae architectures (the
+        ones the kernel implements) and pure JAX otherwise; "on" forces
+        the kernel (interpret-mode off-TPU — correct but slow, used by
+        tests) and rejects other architectures; "off" forces pure JAX.
+        Whenever the kernel is chosen it must build and match the pure-JAX
+        forward on a probe batch within `KERNEL_PARITY_TOL`, or
+        construction raises.
         """
         from repro.kernels import ops as kernel_ops
 
@@ -969,40 +996,29 @@ class SurrogateEngine:
                 f"rebuild the stale artifact")
         jax_predict = _make_jax_predict(two_cfg, params, feat.adj, feat.mask)
         predict, backend = jax_predict, "jax"
-        want_kernel = (use_kernel == "on"
-                       or (use_kernel == "auto" and kernel_ops.ON_TPU))
-        if use_kernel == "on" and two_cfg.gnn.arch not in ("gcn", "gsae"):
+        kernel_arch = two_cfg.gnn.arch in ("gcn", "gsae")
+        if use_kernel == "on" and not kernel_arch:
             raise ValueError(
                 f"use_kernel='on' but the gnn_mp kernel does not support "
                 f"arch={two_cfg.gnn.arch!r} (only gcn/gsae)")
-        if want_kernel and two_cfg.gnn.arch in ("gcn", "gsae"):
-            try:
-                kp = _make_kernel_predict(two_cfg, params, feat.adj,
-                                          feat.mask)
-                probe = _probe_configs(feat.sizes)
-                import jax.numpy as jnp
-                Xp = jnp.asarray(feat(probe))
-                parity_ok = np.allclose(np.asarray(kp(Xp)),
-                                        np.asarray(jax_predict(Xp)),
-                                        atol=parity_atol)
-            except Exception:
-                if use_kernel == "on":
-                    raise
-                parity_ok = False   # auto: fall back to pure JAX
-            if parity_ok:
-                predict, backend = kp, "pallas"
-            elif use_kernel == "on":
+        if use_kernel == "on" or (use_kernel == "auto" and kernel_arch
+                                  and kernel_ops.on_tpu()):
+            kp = _make_kernel_predict(two_cfg, params, feat.adj, feat.mask)
+            Xp = feat(_probe_configs(feat.sizes))
+            got, want = np.asarray(kp(Xp)), np.asarray(jax_predict(Xp))
+            if not np.allclose(got, want, rtol=KERNEL_PARITY_TOL,
+                               atol=KERNEL_PARITY_TOL):
                 raise RuntimeError(
-                    "use_kernel='on' but the gnn_mp kernel path failed the "
-                    f"parity check against pure JAX (atol={parity_atol})")
+                    "the gnn_mp kernel path failed the parity check against "
+                    f"pure JAX: max |diff| {np.abs(got - want).max():.3g} "
+                    f"(tolerance {KERNEL_PARITY_TOL})")
+            predict, backend = kp, "pallas"
 
         n_dev = _resolve_devices(devices)
+        dispatch = _over_devices(predict, n_dev)
 
         def prepare(configs):
             return feat(configs)            # host: lookup + dynamic sweep
-
-        def dispatch(X):
-            return predict(_maybe_shard(np.asarray(X), n_dev))
 
         def collect(y_dev):
             y = np.asarray(y_dev)           # blocks on device compute
@@ -1051,6 +1067,7 @@ class SurrogateEngine:
         jax_predict = _make_jax_predict(two_cfg, params, feat.adj,
                                         feat.mask)
         n_dev = _resolve_devices(devices)
+        on_devices = _over_devices(jax_predict, n_dev)
 
         def prepare(configs):
             X = feat.normalized(configs)
@@ -1059,8 +1076,7 @@ class SurrogateEngine:
                 axis=-1)
 
         def dispatch(Xa):
-            return jax_predict(_maybe_shard(np.ascontiguousarray(Xa),
-                                            n_dev))
+            return on_devices(np.ascontiguousarray(Xa))
 
         def collect(y_dev):
             y = np.asarray(y_dev)
@@ -1087,8 +1103,8 @@ class SurrogateEngine:
         group runs as one vmapped jit over the member axis (pure-JAX path
         — the Pallas gnn_mp dispatch stays single-model for now). The std
         is denormalized with the same per-target scale as the mean; the
-        ssim flip (1 - ssim) leaves its std unchanged. ``devices`` shards
-        each chunk's config axis (the vmapped member axis stays local);
+        ssim flip (1 - ssim) leaves its std unchanged. ``devices`` spreads
+        chunks over devices as in `from_gnn`;
         ``overlap`` pipelines featurization exactly as in `from_gnn` —
         dispatch enqueues every member group before collect blocks.
         """
@@ -1107,19 +1123,18 @@ class SurrogateEngine:
                 B = X.shape[0]
                 adj = jnp.broadcast_to(A, (B,) + A.shape)
                 mask = jnp.broadcast_to(m_row, (B,) + m_row.shape)
-                return jax.vmap(lambda p: models_lib.predict(
-                    g_cfg, p, adj, X, mask)[0])(params)
+                with jax.default_matmul_precision("highest"):
+                    return jax.vmap(lambda p: models_lib.predict(
+                        g_cfg, p, adj, X, mask)[0])(params)
             group_fns.append(gf)
 
         n_obj = len(models_lib.TARGETS)
         n_dev = _resolve_devices(devices)
+        dispatch = _over_devices(lambda X: [gf(X) for gf in group_fns],
+                                 n_dev)
 
         def prepare(configs):
             return feat(configs)
-
-        def dispatch(X):
-            Xs = _maybe_shard(np.asarray(X), n_dev)
-            return [gf(Xs) for gf in group_fns]
 
         def collect(handles):
             Y = np.concatenate([np.asarray(h) for h in handles], 0)

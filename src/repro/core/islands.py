@@ -52,6 +52,7 @@ scalar oracle as ``SAMPLERS["islands_ref"]``), and as
 """
 from __future__ import annotations
 
+import functools
 import sys
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
@@ -356,43 +357,45 @@ def _dense_ranks(F: np.ndarray) -> np.ndarray:
     return R
 
 
-_RANKS_JIT = None
-
-
-def _ranks_kernel_jax(R: np.ndarray) -> np.ndarray:
-    """Jitted batched front-peeling over int32 rank tensors, SPMD-sharded
-    over the island axis (`meshes.shard_leading_axis`). Every op is
-    island-local (the einsum contracts within each island), so sharding
-    adds zero communication and the result equals
-    `dse.non_dominated_ranks_batched` exactly."""
-    global _RANKS_JIT
+@functools.lru_cache(maxsize=None)
+def _ranks_jit():
+    """The jitted batched front-peeling kernel over (I, n, m) int32 rank
+    tensors; built on first use so importing this module needs no JAX."""
     import jax
     import jax.numpy as jnp
 
-    if _RANKS_JIT is None:
-        def kern(R):
-            less = jnp.all(R[:, :, None, :] <= R[:, None, :, :], axis=-1)
-            D = (less & ~jnp.transpose(less, (0, 2, 1))).astype(jnp.int32)
-            dom = D.sum(1)
+    def kern(R):
+        less = jnp.all(R[:, :, None, :] <= R[:, None, :, :], axis=-1)
+        D = (less & ~jnp.transpose(less, (0, 2, 1))).astype(jnp.int32)
+        dom = D.sum(1)
 
-            def cond(s):
-                return jnp.any(s[0] == 0)
+        def cond(s):
+            return jnp.any(s[0] == 0)
 
-            def body(s):
-                dom, ranks, r = s
-                cur = dom == 0
-                ranks = jnp.where(cur, r, ranks)
-                dec = jnp.einsum("bij,bi->bj", D, cur.astype(jnp.int32))
-                return jnp.where(cur, -1, dom - dec), ranks, r + 1
+        def body(s):
+            dom, ranks, r = s
+            cur = dom == 0
+            ranks = jnp.where(cur, r, ranks)
+            dec = jnp.einsum("bij,bi->bj", D, cur.astype(jnp.int32))
+            return jnp.where(cur, -1, dom - dec), ranks, r + 1
 
-            init = (dom, jnp.full(dom.shape, -1, jnp.int32), jnp.int32(0))
-            return jax.lax.while_loop(cond, body, init)[1]
+        init = (dom, jnp.full(dom.shape, -1, jnp.int32), jnp.int32(0))
+        return jax.lax.while_loop(cond, body, init)[1]
 
-        _RANKS_JIT = jax.jit(kern)
+    return jax.jit(kern)
+
+
+def _ranks_kernel_jax(R: np.ndarray) -> np.ndarray:
+    """`_ranks_jit`, SPMD-sharded over the island axis
+    (`meshes.shard_leading_axis`). Every op is island-local (the einsum
+    contracts within each island): the only communication is the while
+    loop's scalar "any front left" condition, and the result equals
+    `dse.non_dominated_ranks_batched` exactly."""
+    import jax.numpy as jnp
 
     from repro.distributed import meshes as M
     Rdev = M.shard_leading_axis(jnp.asarray(R), len(R), axis_name="island")
-    return np.asarray(_RANKS_JIT(Rdev), np.int64)
+    return np.asarray(_ranks_jit()(Rdev), np.int64)
 
 
 def fleet_ranks(F: np.ndarray, backend: str = "auto") -> np.ndarray:

@@ -183,12 +183,12 @@ def shard_leading_axis(tree, n_leading: int, axis_name: str = "shard",
     """SPMD-shard the leading axis of every array in `tree` over devices.
 
     For programs whose leading-axis slices are fully independent (ensemble
-    members in `training.fit_ensemble`, islands in `islands.run_islands`,
-    config rows in the surrogate engine's chunk dispatch —
-    `engine.SurrogateEngine.from_gnn(devices=...)`) sharding the leading
-    axis runs the slices in parallel with ZERO cross-device
-    communication, so per-slice results stay bit-identical to the
-    unsharded run. Uses the largest device prefix whose size divides
+    members in `training.fit_ensemble`, islands in `islands.run_islands`)
+    sharding the leading axis runs the slices in parallel with ZERO
+    cross-device communication. Integer programs (the island rank kernel)
+    stay bit-identical to the unsharded run; a float program's slices are
+    compiled at the per-device shape, and on a TPU their last bits can
+    depend on that shape. Uses the largest device prefix whose size divides
     `n_leading` (capped at `max_devices` when given); returns `tree`
     unchanged when that prefix is a single device.
     """

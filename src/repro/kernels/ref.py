@@ -27,10 +27,6 @@ def flash_attention_ref(q, k, v, *, causal=True):
     return o.reshape(B, H, S, D)
 
 
-def lut_eval_ref_sized(lut, a, b, wb: int):
-    return lut[(a << wb) | b]
-
-
 def ssm_scan_ref(a, b, y0):
     """Diagonal linear recurrence y_t = a_t * y_{t-1} + b_t.
     a,b: (T,D) f32; y0: (D,). Returns ys (T,D) and y_final (D,)."""

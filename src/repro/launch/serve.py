@@ -890,6 +890,8 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=6)
     args = ap.parse_args()
+    from repro.core.artifacts import enable_compilation_cache
+    enable_compilation_cache()
     (_demo_eval if args.demo == "eval" else _demo_lm)(args)
 
 

@@ -95,18 +95,44 @@ def test_accuracy_ssim_batch_ragged_chunk(imgset):
     np.testing.assert_allclose(whole, per, atol=1e-6)
 
 
-def test_accuracy_ssim_batch_pallas_backend(imgset):
-    """The Pallas lut_eval route under vmap matches the pure-JAX gather
-    (interpret mode on CPU)."""
+def test_accuracy_ssim_batch_lut_app_matches_scalar(imgset):
+    """An app whose multipliers go through the LUT gather (gaussian:
+    mul8x4 with constant coefficient columns) labels each config exactly
+    as the closure-based scalar model does."""
     g, _ = imgset
-    app = apps.APPS["gaussian"]                  # mul8x4 -> LUT units
+    app = apps.APPS["gaussian"]
     entries = _entries(app)
+    assert any(n.kind in lib.LUT_DOMAINS for n in app.unit_nodes)
     C = _rand_configs(app, entries, 4, seed=5)
-    jnp_scores = apps.accuracy_ssim_batch(app, entries, C, g, chunk=4,
-                                          backend="jnp")
-    pl_scores = apps.accuracy_ssim_batch(app, entries, C, g, chunk=4,
-                                         backend="pallas")
-    np.testing.assert_allclose(pl_scores, jnp_scores, atol=1e-6)
+    got = apps.accuracy_ssim_batch(app, entries, C, g, chunk=4)
+    for i, row in enumerate(C):
+        choice = {node.id: entries[node.kind][c]
+                  for node, c in zip(app.unit_nodes, row)}
+        assert got[i] == pytest.approx(apps.accuracy_ssim(app, choice, g),
+                                       abs=2e-5), i
+
+
+@pytest.mark.parametrize("kind,wa,wb,idx,M", [
+    ("mul8", 8, 8, 5, 4096), ("mul8x4", 8, 4, 3, 4096),
+    ("add8", 8, 8, 7, 4096),
+    # lengths that are not a multiple of any block or vector width
+    ("mul8x4", 8, 4, 2, 4096 + 700), ("add8", 8, 8, 4, 1023),
+])
+def test_lut_gather_sweep(kind, wa, wb, idx, M):
+    """The labeler's gather over a stacked truth table reads entry
+    ``idx``'s value for every (a, b): equal to evaluating the unit."""
+    import jax
+
+    entries = tuple(lib.build_library(kind))
+    table = jnp.asarray(lib.stacked_lut(entries, wa, wb))
+    rng = np.random.default_rng(7)
+    a = jnp.asarray(rng.integers(0, 1 << wa, M), jnp.int32)
+    b = jnp.asarray(rng.integers(0, 1 << wb, M), jnp.int32)
+    gather = jax.jit(apps.lut_gather, static_argnums=3)
+    got = gather(table, (idx << wa) | a, b, wb)
+    direct = entries[idx].inst.fn()(a, b)
+    assert got.shape == (M,)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(direct))
 
 
 def test_lut_domain_guard_raises(imgset):

@@ -211,10 +211,72 @@ def test_use_kernel_on_rejects_unsupported_arch(small_surrogate):
     with pytest.raises(ValueError, match="gat"):
         SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
                                  use_kernel="on")
-    # auto silently uses the pure-JAX path for unsupported archs
+    # auto picks pure JAX for an arch the kernel does not implement
     eng = SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
                                    use_kernel="auto")
     assert eng.backend == "jax"
+
+
+@pytest.fixture
+def steered_to_tpu(monkeypatch):
+    """The engine's TPU branch on this CPU host: `ops.on_tpu` says TPU,
+    while the kernel itself still runs in interpret mode."""
+    from repro.kernels import gnn_mp as mp_lib
+    from repro.kernels import ops
+
+    real = mp_lib.gnn_mp
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    monkeypatch.setattr(mp_lib, "gnn_mp",
+                        lambda *a, interpret, **kw: real(*a, interpret=True,
+                                                         **kw))
+
+
+def test_auto_on_tpu_means_the_kernel(small_surrogate, steered_to_tpu):
+    import jax
+    from repro.core import gnn, models
+
+    app, entries, ds, two_cfg, params = small_surrogate
+    cfgs = _app_configs(app, entries, 12, seed=6)
+    eng = SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
+                                   use_kernel="auto")
+    assert eng.backend == "pallas"
+    ref = SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
+                                   use_kernel="off")
+    np.testing.assert_allclose(eng(cfgs), ref(cfgs), rtol=1e-4, atol=1e-4)
+    gat = models.TwoStageConfig(gnn=gnn.GNNConfig(
+        arch="gat", n_layers=1, hidden=8, feature_dim=ds.x.shape[-1]))
+    eng = SurrogateEngine.from_gnn(gat, models.init(jax.random.PRNGKey(0),
+                                                    gat),
+                                   ds, app, entries, use_kernel="auto")
+    assert eng.backend == "jax"
+
+
+@pytest.mark.parametrize("use_kernel", ["on", "auto"])
+def test_kernel_parity_failure_raises(small_surrogate, steered_to_tpu,
+                                      monkeypatch, use_kernel):
+    """A kernel that misses the parity tolerance stops construction: no
+    mode falls back to pure JAX."""
+    from repro.core import engine as engine_lib
+
+    app, entries, ds, two_cfg, params = small_surrogate
+    monkeypatch.setattr(engine_lib, "KERNEL_PARITY_TOL", -1.0)
+    with pytest.raises(RuntimeError, match="parity"):
+        SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
+                                 use_kernel=use_kernel)
+
+
+def test_kernel_build_error_propagates(small_surrogate, steered_to_tpu,
+                                       monkeypatch):
+    from repro.core import engine as engine_lib
+
+    def broken(*a, **kw):
+        raise NotImplementedError("kernel does not lower")
+
+    app, entries, ds, two_cfg, params = small_surrogate
+    monkeypatch.setattr(engine_lib, "_make_kernel_predict", broken)
+    with pytest.raises(NotImplementedError, match="lower"):
+        SurrogateEngine.from_gnn(two_cfg, params, ds, app, entries,
+                                 use_kernel="auto")
 
 
 def test_rforest_engine_matches_flat_features(small_surrogate):

@@ -1,16 +1,15 @@
-"""Device-sharded + pipelined `SurrogateEngine` execution.
+"""Multi-device + pipelined `SurrogateEngine` execution.
 
 Two properties are proven here:
 
-* **Sharded drain is invisible in values** — an engine built with
-  ``devices=0`` (all local devices) on a forced-8-device host
+* **Spreading chunks over devices is invisible in values** — an engine
+  built with ``devices=0`` (all local devices) on a forced-8-device host
   (`XLA_FLAGS=--xla_force_host_platform_device_count=8`, the same
-  subprocess idiom as tests/test_islands_batched.py) produces rows
-  bit-identical to a 1-device host, for both the direct ``__call__``
-  path and the cross-request ``submit``/``drain`` path, with the memo
-  cache on and off. Per-config compute is fully independent, so
-  `meshes.shard_leading_axis` introduces zero cross-device
-  communication.
+  subprocess idiom as tests/test_islands_batched.py) puts successive
+  chunks on every device and produces rows bit-identical to a 1-device
+  host, for both the direct ``__call__`` path and the cross-request
+  ``submit``/``drain`` path, with the memo cache on and off. Each device
+  runs the single-device program on whole chunks.
 * **Overlap is invisible in values and visible in timings** — the
   pipelined chunk executor (featurize worker + async dispatch + deferred
   collect) returns exactly the serial path's rows while
@@ -285,9 +284,14 @@ _DEVICE_SCRIPT = textwrap.dedent("""
                                        chunk_size=16, devices=0,
                                        cache=cache)
         out["shard_width_" + label] = eng.devices
+        pb = eng._pipeline
+        X = pb.prepare(cfg_a[:16])
+        out["chunk_devices_" + label] = len(
+            {d.id for _ in range(jax.device_count())
+             for d in pb.dispatch(X).devices()})
         out["call_" + label] = rows(eng(cfg_a))
         # cross-request drain path: queued submissions coalesce into one
-        # fused sharded wave
+        # fused wave
         futs = [eng.submit(cfg_b[i:i + 12]) for i in range(0, 48, 12)]
         assert eng.drain() == 4
         out["drain_" + label] = rows(np.concatenate(
@@ -307,14 +311,16 @@ def _run_with_devices(n):
 
 
 def test_sharded_drain_bit_identical_across_1_and_8_devices():
-    """Acceptance: a drain wave sharded over 8 forced host devices serves
-    the exact float rows of the single-device engine — for __call__ and
-    submit/drain, memo cache on and off."""
+    """Acceptance: an engine spreading its chunks over 8 forced host
+    devices serves the exact float rows of the single-device engine —
+    for __call__ and submit/drain, memo cache on and off."""
     one = _run_with_devices(1)
     eight = _run_with_devices(8)
     assert one["devices"] == 1 and eight["devices"] == 8
     assert one["shard_width_memo"] == 1
     assert eight["shard_width_memo"] == 8
+    assert one["chunk_devices_memo"] == 1
+    assert eight["chunk_devices_memo"] == 8    # every device gets chunks
     for key in ("call_memo", "call_nomemo", "drain_memo", "drain_nomemo"):
         assert one[key] == eight[key], f"{key} diverged across devices"
     # the two paths agree with each other as well (same memoized rows)

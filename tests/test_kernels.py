@@ -52,27 +52,6 @@ def test_flash_attention_bf16():
                                rtol=0.05, atol=0.05)
 
 
-@pytest.mark.parametrize("kind,wa,wb,idx,M", [
-    ("mul8", 8, 8, 5, 4096), ("mul8x4", 8, 4, 3, 4096),
-    ("add8", 8, 8, 7, 4096),
-    # ragged: not a block multiple — must pad to the block size and slice,
-    # not silently degrade to one whole-array block
-    ("mul8x4", 8, 4, 2, 4096 + 700), ("add8", 8, 8, 4, 1023),
-])
-def test_lut_eval_sweep(kind, wa, wb, idx, M):
-    from repro.accel import library as lib
-    e = lib.build_library(kind)[idx]
-    lut = ops.build_lut(e.inst.fn(), wa, wb)
-    a = jnp.asarray(RNG.integers(0, 1 << wa, M), jnp.int32)
-    b = jnp.asarray(RNG.integers(0, 1 << wb, M), jnp.int32)
-    got = ops.lut_eval(lut, a, b, wb, block=1024)
-    want = ops.lut_eval(lut, a, b, wb, backend="ref")
-    direct = e.inst.fn()(a, b)
-    assert got.shape == (M,)
-    assert (got == want).all()
-    assert (got == direct).all()
-
-
 @pytest.mark.parametrize("T,D,block", [
     (64, 8, 16), (256, 32, 128), (128, 128, 32), (100, 16, 100),
 ])
@@ -104,3 +83,24 @@ def test_gnn_mp_inside_surrogate():
     want = gnn._layer(cfg, lp, adj, x, mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_importing_the_package_touches_no_device():
+    """The TPU is picked at dispatch (`ops.on_tpu`), never at import: a
+    process that only imports the main path's modules has initialized no
+    backend, so it holds no chip."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import repro.kernels.ops, repro.core.engine, "
+            "repro.core.pipeline, repro.core.islands, repro.launch.serve\n"
+            "from jax._src import xla_bridge\n"
+            "print(len(xla_bridge._backends))")
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(__file__), "..", "src"))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split()[-1] == "0"
+    assert not ops.on_tpu()          # this test process runs on the CPU

@@ -88,6 +88,57 @@ def test_store_memory_only_never_hits_disk(tmp_path):
     assert store.has(key)                     # memory tier still serves it
 
 
+_CACHE_SCRIPT = """
+import json, jax, jax.numpy as jnp
+from repro.core import artifacts
+path = artifacts.enable_compilation_cache()
+if path != str(artifacts.DEFAULT_COMPILE_CACHE):
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(5.0)).block_until_ready()
+print(json.dumps([path, jax.config.jax_compilation_cache_dir]))
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compilation_cache_directory(tmp_path, from_env):
+    """With JAX_COMPILATION_CACHE_DIR set, executables go there and the
+    helper sets no directory of its own; without it, one fixed directory
+    inside the checkout."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from repro.core import artifacts
+
+    env = dict(os.environ, PYTHONPATH=str(Path(artifacts.__file__)
+                                          .resolve().parents[2]))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jcc")
+    r = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    path, configured = json.loads(r.stdout.splitlines()[-1])
+    want = (str(tmp_path / "jcc") if from_env
+            else str(artifacts.DEFAULT_COMPILE_CACHE))
+    assert path == configured == want
+    if from_env:
+        assert any((tmp_path / "jcc").iterdir())
+    else:
+        assert artifacts.DEFAULT_COMPILE_CACHE.is_dir()
+        assert (artifacts.DEFAULT_COMPILE_CACHE.parent / "src").is_dir()
+
+
+def test_store_leaves_the_compile_cache_alone(tmp_path):
+    import jax
+
+    before = jax.config.jax_compilation_cache_dir
+    ArtifactStore(str(tmp_path))
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / "xla_cache").exists()
+
+
 def test_store_key_spec_sensitivity():
     c1, c2 = tiny_cfg(), tiny_cfg(dse_budget=999)
     # dse_budget is a search-stage knob: dataset/train keys must not move

@@ -13,6 +13,7 @@ _SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, sys
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro.configs import REDUCED_ARCHS
     from repro.configs.base import ShapeConfig
     from repro.launch import steps as S
@@ -20,7 +21,8 @@ _SCRIPT = textwrap.dedent("""
 
     arch = sys.argv[1]
     kind = sys.argv[2]
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = REDUCED_ARCHS[arch]
     if kind == "train":
         shape = ShapeConfig("t", 16, 4, "train", grad_accum=2)
@@ -73,6 +75,7 @@ _TP_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json, sys
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     from repro.configs import REDUCED_ARCHS
     from repro.configs.base import ShapeConfig
     from repro.launch import steps as S
@@ -83,7 +86,8 @@ _TP_SCRIPT = textwrap.dedent("""
     import numpy as np
 
     preset = sys.argv[1]
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = REDUCED_ARCHS["granite-3-2b"]
     shape = ShapeConfig("t", 32, 8, "train", grad_accum=2)
     pipe = TokenPipeline(cfg.vocab_size, 32, 8)
@@ -127,11 +131,13 @@ _ALIGN_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import json
     import jax, jax.numpy as jnp
+    from jax.sharding import AxisType
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.distributed import meshes as M
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     # kv_flat=32 over model(4): 8 cols/device splits head_dim=16 -> replicate
     s = M.spec_for(mesh, (64, 32), ("embed", "kv_flat"), M.BASE_RULES,
                    head_dim=16)

@@ -181,8 +181,6 @@ def test_hlo_profile_trip_count_multiplication():
     assert prof["dot_flops"] == pytest.approx(5 * 2 * 32 * 64 * 64, rel=0.05)
     # XLA's own analysis counts the body once: we must exceed it
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # older jax returns [dict]
-        ca = ca[0]
     assert prof["dot_flops"] > ca["flops"] * 2
 
 
