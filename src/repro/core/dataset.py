@@ -383,7 +383,7 @@ class ConfigFeaturizer:
                 self._members = members
             return self._members
 
-    def dynamic_raw(self, C: np.ndarray) -> np.ndarray:
+    def dynamic_raw(self, C: np.ndarray, stats=None) -> np.ndarray:
         """(B, n_graph_nodes, n_dyn) float32 dynamic timing features.
 
         One `batch_oracle.timing_batch` sweep per batch, reduced onto the
@@ -391,13 +391,20 @@ class ConfigFeaturizer:
         log1p-compressed where the schema says so — the single source of
         the dynamic columns for BOTH the build path (`raw`) and the DSE
         hot path (`normalized`), which is what makes them bit-identical.
+
+        The sweep and the probe run in the profiler spans
+        ``featurize.timing`` and ``featurize.probe``; an engine's
+        `EngineStats` passed as ``stats`` also adds their durations to its
+        ``timing_s`` and ``probe_s``.
         """
         from repro.accel import batch_oracle
         fields = self.schema.dynamic_fields
-        rep = batch_oracle.timing_batch(self._app, self._entries, C)
+        with _span(stats, "featurize.timing", "timing_s"):
+            rep = batch_oracle.timing_batch(self._app, self._entries, C)
         if any(f in apps_lib.PROBE_FIELDS for f in fields):
-            rep.update(batch_oracle.probe_batch(self._app, self._entries,
-                                                C))
+            with _span(stats, "featurize.probe", "probe_s"):
+                rep.update(batch_oracle.probe_batch(self._app,
+                                                    self._entries, C))
         members = self._member_index()
         out = np.empty((C.shape[0], self.n_nodes, len(fields)), np.float32)
         for f_idx, f in enumerate(fields):
@@ -447,8 +454,9 @@ class ConfigFeaturizer:
         sd_d = np.asarray(x_std[dyn], np.float32)
         self._norm = (base, tables, mu_d, sd_d)
 
-    def normalized(self, configs) -> np.ndarray:
-        """(B, n_pad, F) features normalized with the dataset stats."""
+    def normalized(self, configs, stats=None) -> np.ndarray:
+        """(B, n_pad, F) features normalized with the dataset stats
+        (``stats``: the engine's `EngineStats`, see `dynamic_raw`)."""
         if self._norm is None:
             raise RuntimeError("call set_norm(x_mean, x_std) first")
         base, tables, mu_d, sd_d = self._norm
@@ -460,8 +468,16 @@ class ConfigFeaturizer:
             # same float32 cast + elementwise standardization the build
             # path applies to the whole raw tensor -> bit-identical rows
             X[:, :self.n_nodes, self.schema.dynamic_slice] = \
-                (self.dynamic_raw(C) - mu_d) / sd_d
+                (self.dynamic_raw(C, stats) - mu_d) / sd_d
         return X
+
+
+def _span(stats, name: str, counter: str):
+    """``stats.span(name, counter)``, or the bare profiler span when no
+    engine counts this featurization (dataset building)."""
+    if stats is None:
+        return jax.profiler.TraceAnnotation(name)
+    return stats.span(name, counter)
 
 
 def _entries_sig(entries: Dict[str, Sequence]) -> Tuple:

@@ -28,7 +28,8 @@ this module provides the production implementation of that callable:
       timing sweep + functional probe) while chunk *k* executes on
       device, and host transfers are deferred until every chunk is in
       flight — the LM decode-pipelining idiom. ``stats.overlap_fraction``
-      reports how much featurization was hidden;
+      reports the share of featurization the calling thread did not wait
+      for;
     * **config-key memoization** — NSGA-II/III re-evaluations of surviving
       parents (and the stagnation-restart re-injections) are free across
       generations; duplicates inside a single batch are evaluated once;
@@ -38,7 +39,9 @@ this module provides the production implementation of that callable:
       forward by a parity check that raises on failure (there is no
       silent fallback; ``interpret=True`` off-TPU when forced);
     * **per-call stats** — configs/sec, cache hit rate, chunk/padding
-      counts (`EngineStats`), surfaced into ``PipelineResult.metrics``.
+      counts and per-phase timers (`EngineStats`), surfaced into
+      ``PipelineResult.metrics``; each timer is the summed duration of a
+      profiler span (`EngineStats.span`), so a trace names every phase.
 
 Featurization is vectorized through the shared
 `repro.core.dataset.ConfigFeaturizer`: every config of one accelerator
@@ -52,6 +55,7 @@ benchmarks/engine_bench.py for the batched-vs-naive throughput numbers.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue as queue_lib
 import threading
@@ -117,18 +121,31 @@ class EngineStats:
                       single-device; set at engine construction and
                       preserved across `reset_stats`).
         featurize_s:  host time in the pipelined backend's prepare stage
-                      (featurization: table lookup + dynamic timing
-                      sweep + functional probe).
+                      on the prefetch worker (featurization: table lookup
+                      + dynamic timing sweep + functional probe).
         dispatch_s:   host time issuing device computation (non-blocking
                       under JAX async dispatch, so this is enqueue cost,
                       not compute).
         collect_s:    time blocked on device→host transfer + objective
                       post-processing (denorm, ssim flip). Device compute
                       not hidden by the pipeline surfaces here.
-        overlapped_s: the slice of ``featurize_s`` that ran while earlier
-                      chunks were executing on device — featurization the
-                      pipeline hid entirely. ``overlap_fraction`` is the
-                      hidden share.
+        feature_wait_s: time the calling thread waited for the prefetch
+                      worker's features (pipelined calls only; it holds
+                      the queue hand-offs too, so it can pass
+                      ``featurize_s`` by those when nothing else keeps
+                      the calling thread busy). ``overlap_fraction`` is
+                      the share of ``featurize_s`` it did not wait for.
+        timing_s:     the part of ``featurize_s`` in the timing sweep
+                      (`batch_oracle.timing_batch`).
+        probe_s:      the part of ``featurize_s`` in the functional probe
+                      (`batch_oracle.probe_batch`).
+        memo_s:       memo key building and lookup, plus cache insertion,
+                      eviction and row assembly.
+
+    ``wall_time_s`` and the timers from ``featurize_s`` on are summed
+    durations of the engine's `span`s (``engine.*`` on the calling
+    thread, ``featurize.*`` where features are made), so a profiler
+    trace and the counters measure the same intervals.
     """
     calls: int = 0
     configs: int = 0
@@ -147,7 +164,10 @@ class EngineStats:
     featurize_s: float = 0.0
     dispatch_s: float = 0.0
     collect_s: float = 0.0
-    overlapped_s: float = 0.0
+    feature_wait_s: float = 0.0
+    timing_s: float = 0.0
+    probe_s: float = 0.0
+    memo_s: float = 0.0
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -157,6 +177,23 @@ class EngineStats:
         with self._lock:
             for name, d in deltas.items():
                 setattr(self, name, getattr(self, name) + d)
+
+    @contextlib.contextmanager
+    def span(self, name: str, counter: Optional[str] = None, **args):
+        """A profiler span (`jax.profiler.TraceAnnotation` with `args`)
+        whose duration is also added to the timer `counter`, so the span
+        and the counter share one start and one end. Yields the
+        annotation (``set_metadata`` adds args known only later). Costs
+        about a microsecond when no profiler is attached."""
+        from jax.profiler import TraceAnnotation
+
+        with TraceAnnotation(name, **args) as ann:
+            t0 = time.perf_counter()
+            try:
+                yield ann
+            finally:
+                if counter is not None:
+                    self.update(**{counter: time.perf_counter() - t0})
 
     def bump_max(self, **candidates) -> None:
         """Atomically raise the named high-water-mark counters."""
@@ -187,10 +224,10 @@ class EngineStats:
 
     @property
     def overlap_fraction(self) -> float:
-        """Share of host featurization hidden behind device compute
-        (0.0 = fully serial; approaches 1.0 when every chunk after the
-        first was featurized while a prior chunk ran on device)."""
-        return self.overlapped_s / self.featurize_s \
+        """Share of host featurization the calling thread did not wait
+        for, ``1 - feature_wait_s / featurize_s`` (0.0 with no
+        featurization; near 0 when the device outruns the featurizer)."""
+        return 1.0 - self.feature_wait_s / self.featurize_s \
             if self.featurize_s else 0.0
 
     def as_dict(self) -> Dict[str, float]:
@@ -209,7 +246,11 @@ class EngineStats:
                     "featurize_s": round(self.featurize_s, 4),
                     "dispatch_s": round(self.dispatch_s, 4),
                     "collect_s": round(self.collect_s, 4),
-                    "overlapped_s": round(self.overlapped_s, 4)}
+                    "feature_wait_s": round(self.feature_wait_s, 4),
+                    "timing_s": round(self.timing_s, 4),
+                    "probe_s": round(self.probe_s, 4),
+                    "memo_s": round(self.memo_s, 4)}
+            overlap = self.overlap_fraction
         snap["cache_hit_rate"] = round(
             snap["cache_hits"] / snap["configs"], 4) if snap["configs"] \
             else 0.0
@@ -221,9 +262,7 @@ class EngineStats:
         total = snap["evaluated"] + snap["padded"]
         snap["padded_fraction"] = round(snap["padded"] / total, 4) \
             if total else 0.0
-        snap["overlap_fraction"] = round(
-            snap["overlapped_s"] / snap["featurize_s"], 4) \
-            if snap["featurize_s"] else 0.0
+        snap["overlap_fraction"] = round(overlap, 4)
         return snap
 
 
@@ -253,8 +292,9 @@ class _ConfigFeaturizer:
         self.adj = feat.adj                                # (N, N) normalized
         self.mask = feat.mask                              # (N,)
 
-    def __call__(self, configs: Sequence[Config]) -> np.ndarray:
-        return self._feat.normalized(configs)
+    def __call__(self, configs: Sequence[Config],
+                 stats: Optional[EngineStats] = None) -> np.ndarray:
+        return self._feat.normalized(configs, stats)
 
 
 # --------------------------------------------------------------------------
@@ -270,9 +310,11 @@ class PipelinedBackend:
     split exists so `SurrogateEngine._eval_chunked` can overlap the
     phases across chunks:
 
-    * ``prepare(configs) -> X`` — host-side featurization (NumPy table
-      lookup plus, under schema v2, the batched timing sweep and the
-      tiny-image functional probe). Runs on the prefetch worker thread.
+    * ``prepare(configs, stats=None) -> X`` — host-side featurization
+      (NumPy table lookup plus, under schema v2, the batched timing sweep
+      and the tiny-image functional probe). Runs on the prefetch worker
+      thread. The engine passes its `EngineStats`, into whose
+      ``timing_s``/``probe_s`` the featurizer counts its parts.
     * ``dispatch(X) -> handle`` — hand the features to the device and
       start compute. Under JAX async dispatch the jitted call returns
       immediately with a future-like device array, so the engine can keep
@@ -601,36 +643,43 @@ class SurrogateEngine:
         return out[:, :self.obj_cols], out[:, self.obj_cols:]
 
     def _call_locked(self, configs: Sequence[Config]) -> np.ndarray:
-        t_wall = time.perf_counter()
-        raw = [tuple(int(v) for v in c) for c in configs]
-        sv = self.schema_version
-        keys = raw if sv is None else [(sv,) + k for k in raw]
-        self.stats.update(calls=1, configs=len(keys))
-        self.stats.bump_max(max_batch=len(keys))
-        miss: List[Config] = []       # raw configs for the backend
-        miss_keys: List[Config] = []  # their (possibly prefixed) memo keys
-        seen = set()
-        for k, r in zip(keys, raw):
-            if k not in self._cache and k not in seen:
-                seen.add(k)
-                miss.append(r)
-                miss_keys.append(k)
-        self.stats.update(cache_hits=len(keys) - len(miss))
-        if miss:
-            t0 = time.perf_counter()
-            rows = self._eval_chunked(miss)
-            self.stats.update(eval_time_s=time.perf_counter() - t0,
-                              evaluated=len(miss))
-            for k, r in zip(miss_keys, rows):
-                self._cache[k] = r
-        out = np.stack([self._cache[k] for k in keys], 0).astype(np.float64)
-        if not self.cache_enabled:
-            self._cache.clear()
-        elif len(self._cache) > self.max_cache:
-            drop = len(self._cache) - self.max_cache
-            for k in list(itertools.islice(self._cache, drop)):
-                del self._cache[k]
-        self.stats.update(wall_time_s=time.perf_counter() - t_wall)
+        st = self.stats
+        st.update(calls=1, configs=len(configs))
+        st.bump_max(max_batch=len(configs))
+        call = st.calls
+        with st.span("engine.call", "wall_time_s", call=call,
+                     configs=len(configs)) as call_span:
+            with st.span("engine.memo", "memo_s", call=call):
+                raw = [tuple(int(v) for v in c) for c in configs]
+                sv = self.schema_version
+                keys = raw if sv is None else [(sv,) + k for k in raw]
+                miss: List[Config] = []       # raw configs for the backend
+                miss_keys: List[Config] = []  # their (prefixed) memo keys
+                seen = set()
+                for k, r in zip(keys, raw):
+                    if k not in self._cache and k not in seen:
+                        seen.add(k)
+                        miss.append(r)
+                        miss_keys.append(k)
+            call_span.set_metadata(misses=len(miss))
+            st.update(cache_hits=len(keys) - len(miss))
+            rows = []
+            if miss:
+                t0 = time.perf_counter()
+                rows = self._eval_chunked(miss, call)
+                st.update(eval_time_s=time.perf_counter() - t0,
+                          evaluated=len(miss))
+            with st.span("engine.assemble", "memo_s", call=call):
+                for k, r in zip(miss_keys, rows):
+                    self._cache[k] = r
+                out = np.stack([self._cache[k] for k in keys],
+                               0).astype(np.float64)
+                if not self.cache_enabled:
+                    self._cache.clear()
+                elif len(self._cache) > self.max_cache:
+                    drop = len(self._cache) - self.max_cache
+                    for k in list(itertools.islice(self._cache, drop)):
+                        del self._cache[k]
         return out
 
     def reset_stats(self) -> None:
@@ -850,14 +899,19 @@ class SurrogateEngine:
                 f"(stats.padded_fraction tracks the running rate)",
                 RuntimeWarning, stacklevel=4)
 
-    def _eval_chunked(self, configs: List[Config]) -> np.ndarray:
+    def _eval_chunked(self, configs: List[Config],
+                      call: int) -> np.ndarray:
         plan = self._plan_chunks(configs)
         self._warn_padding(plan, len(configs))
         if self.overlap and self._pipeline is not None and len(plan) >= 2:
-            return self._eval_pipelined(plan, configs)
+            return self._eval_pipelined(plan, configs, call)
         rows = []
-        for i, take, chunk in plan:
-            y = self._eval_backend(chunk)
+        for idx, (i, take, chunk) in enumerate(plan):
+            # the composed call, retries included, in one span: its phases
+            # are not split here, so no phase timer counts them (the
+            # featurizer's own spans still open inside it)
+            with self.stats.span("engine.backend", call=call, chunk=idx):
+                y = self._eval_backend(chunk)
             if y.shape[0] != len(chunk):
                 raise ValueError(
                     f"backend returned {y.shape[0]} rows for "
@@ -870,7 +924,7 @@ class SurrogateEngine:
         return np.concatenate(rows, 0)
 
     def _eval_pipelined(self, plan: List[Tuple[int, int, List[Config]]],
-                        configs: List[Config]) -> np.ndarray:
+                        configs: List[Config], call: int) -> np.ndarray:
         """Two-stage pipelined execution of the chunk plan (the LM decode
         idiom): ONE worker thread runs the backend's host ``prepare``
         (featurization: table lookup + timing sweep + functional probe)
@@ -886,62 +940,59 @@ class SurrogateEngine:
         phase raises is re-evaluated through `_eval_backend` (the composed
         call, under the engine's RetryPolicy), preserving the serial
         path's retry/nan-guard fault semantics.
+
+        Spans: ``featurize.chunk`` on the worker; ``engine.wait_features``,
+        ``engine.dispatch`` and ``engine.collect`` on the calling thread,
+        each with the engine call number and the plan index.
         """
-        pb = self._pipeline
+        pb, st = self._pipeline, self.stats
         prepared: "queue_lib.Queue" = queue_lib.Queue(maxsize=2)
 
         def featurize_worker() -> None:
             for idx, (_, _, chunk) in enumerate(plan):
-                t0 = time.perf_counter()
                 try:
-                    X = pb.prepare(chunk)
+                    with st.span("featurize.chunk", "featurize_s",
+                                 call=call, chunk=idx):
+                        X = pb.prepare(chunk, st)
                 except BaseException as e:  # noqa: BLE001 — re-raised below
-                    prepared.put((idx, e, time.perf_counter() - t0))
+                    prepared.put((idx, e))
                     return
-                prepared.put((idx, X, time.perf_counter() - t0))
+                prepared.put((idx, X))
 
         worker = threading.Thread(target=featurize_worker, daemon=True,
                                   name="engine-featurize")
         worker.start()
         inflight: List[Tuple[int, Any]] = []   # (plan index, handle|None)
-        feat_s = disp_s = overlapped_s = 0.0
         for k in range(len(plan)):
-            idx, X, dt = prepared.get()
-            feat_s += dt
-            if k > 0:
-                # every chunk after the first featurized while earlier
-                # chunks were executing on device (dispatch returned
-                # without blocking), so its prepare cost was hidden
-                overlapped_s += dt
+            with st.span("engine.wait_features", "feature_wait_s",
+                         call=call, chunk=k):
+                idx, X = prepared.get()
             if isinstance(X, BaseException):
                 # worker died: this and all later chunks fall back to
                 # the composed serial call in the collect loop
                 inflight.extend((j, None) for j in range(idx, len(plan)))
                 break
-            t0 = time.perf_counter()
             try:
-                handle = pb.dispatch(X)
+                with st.span("engine.dispatch", "dispatch_s", call=call,
+                             chunk=idx):
+                    handle = pb.dispatch(X)
             except BaseException:           # noqa: BLE001 — healed below
                 handle = None
-            disp_s += time.perf_counter() - t0
             inflight.append((idx, handle))
         worker.join()
-        self.stats.update(featurize_s=feat_s, dispatch_s=disp_s,
-                          overlapped_s=overlapped_s)
         rows: List[Optional[np.ndarray]] = [None] * len(plan)
-        coll_s = 0.0
         for idx, handle in inflight:
             i, take, chunk = plan[idx]
-            t0 = time.perf_counter()
-            y = None
-            if handle is not None:
-                try:
-                    y = np.asarray(pb.collect(handle))
-                except BaseException:       # noqa: BLE001 — healed below
-                    y = None
-            if y is None:
-                y = self._eval_backend(chunk)
-            coll_s += time.perf_counter() - t0
+            with st.span("engine.collect", "collect_s", call=call,
+                         chunk=idx):
+                y = None
+                if handle is not None:
+                    try:
+                        y = np.asarray(pb.collect(handle))
+                    except BaseException:   # noqa: BLE001 — healed below
+                        y = None
+                if y is None:
+                    y = self._eval_backend(chunk)
             if y.shape[0] != len(chunk):
                 raise ValueError(
                     f"backend returned {y.shape[0]} rows for "
@@ -951,7 +1002,6 @@ class SurrogateEngine:
                 part = self._guard_rows(configs[i:i + take], part)
             rows[idx] = part
             self.stats.update(chunks=1)
-        self.stats.update(collect_s=coll_s)
         return np.concatenate(rows, 0)
 
     # -- constructors ------------------------------------------------------
@@ -1017,8 +1067,8 @@ class SurrogateEngine:
         n_dev = _resolve_devices(devices)
         dispatch = _over_devices(predict, n_dev)
 
-        def prepare(configs):
-            return feat(configs)            # host: lookup + dynamic sweep
+        def prepare(configs, stats=None):
+            return feat(configs, stats)     # host: lookup + dynamic sweep
 
         def collect(y_dev):
             y = np.asarray(y_dev)           # blocks on device compute
@@ -1069,8 +1119,8 @@ class SurrogateEngine:
         n_dev = _resolve_devices(devices)
         on_devices = _over_devices(jax_predict, n_dev)
 
-        def prepare(configs):
-            X = feat.normalized(configs)
+        def prepare(configs, stats=None):
+            X = feat.normalized(configs, stats)
             return np.concatenate(
                 [X, np.broadcast_to(block, (X.shape[0],) + block.shape)],
                 axis=-1)
@@ -1133,8 +1183,8 @@ class SurrogateEngine:
         dispatch = _over_devices(lambda X: [gf(X) for gf in group_fns],
                                  n_dev)
 
-        def prepare(configs):
-            return feat(configs)
+        def prepare(configs, stats=None):
+            return feat(configs, stats)
 
         def collect(handles):
             Y = np.concatenate([np.asarray(h) for h in handles], 0)

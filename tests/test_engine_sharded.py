@@ -13,9 +13,10 @@ Two properties are proven here:
 * **Overlap is invisible in values and visible in timings** — the
   pipelined chunk executor (featurize worker + async dispatch + deferred
   collect) returns exactly the serial path's rows while
-  ``stats.overlap_fraction``/``featurize_s``/``dispatch_s``/``collect_s``
-  record the interleaving; phase failures heal through the composed
-  backend call.
+  ``featurize_s``/``feature_wait_s``/``dispatch_s``/``collect_s`` record
+  the interleaving (``overlap_fraction`` is the share of featurization
+  the calling thread did not wait for); phase failures heal through the
+  composed backend call.
 
 Satellites of the same PR ride along: the explicit ``chunk_size=None``
 no-chunking mode (`queued_view`'s former ``1 << 30`` sentinel) and the
@@ -46,8 +47,9 @@ def _rows_for(configs):
     return np.stack([a.sum(1), a.max(1), a.min(1) - 1.0, a.mean(1)], 1)
 
 
-def _fake_pipeline(prepare_sleep=0.0, collect_sleep=0.0, log=None):
-    def prepare(configs):
+def _fake_pipeline(prepare_sleep=0.0, collect_sleep=0.0, log=None,
+                   dispatch_sleep=0.0):
+    def prepare(configs, stats=None):
         if prepare_sleep:
             time.sleep(prepare_sleep)
         if log is not None:
@@ -55,6 +57,8 @@ def _fake_pipeline(prepare_sleep=0.0, collect_sleep=0.0, log=None):
         return np.asarray(configs, np.float64)
 
     def dispatch(X):
+        if dispatch_sleep:
+            time.sleep(dispatch_sleep)
         if log is not None:
             log.append(("dispatch", len(X)))
         return X
@@ -89,35 +93,43 @@ def test_overlap_rows_bit_identical_to_serial():
     np.testing.assert_array_equal(r_on, _rows_for(cfgs))
 
 
-def test_overlap_fraction_shows_featurize_compute_interleaving():
-    """With K chunks, every chunk after the first featurizes while prior
-    chunks are in flight: overlapped_s must cover ~ (K-1)/K of the
-    featurize time, and all three phase timers must be populated."""
-    cfgs = _configs(64)
-    eng = SurrogateEngine(_fake_pipeline(prepare_sleep=0.02,
-                                         collect_sleep=0.005),
-                          chunk_size=16)
-    eng(cfgs)
+@pytest.mark.parametrize("sleeps,hidden", [
+    # slow dispatch: the worker featurizes ahead while the calling thread
+    # dispatches, so it waits only for the first of 8 chunks
+    (dict(prepare_sleep=0.01, dispatch_sleep=0.03), True),
+    # slow featurization: the calling thread waits for every chunk but
+    # for the time it spends dispatching the one before
+    (dict(prepare_sleep=0.02, dispatch_sleep=0.002, collect_sleep=0.005),
+     False),
+], ids=["slow_dispatch", "slow_prepare"])
+def test_overlap_fraction_shows_featurize_compute_interleaving(sleeps,
+                                                               hidden):
+    """``overlap_fraction`` is measured: the share of featurization the
+    calling thread did not spend waiting for features."""
+    eng = SurrogateEngine(_fake_pipeline(**sleeps), chunk_size=8)
+    eng(_configs(64))
     d = eng.stats.as_dict()
-    assert d["chunks"] == 4
-    assert d["featurize_s"] >= 4 * 0.02
-    assert d["collect_s"] >= 4 * 0.005
-    assert d["dispatch_s"] >= 0.0
-    # 3 of 4 chunk preparations ran while earlier chunks were in flight
-    assert d["overlapped_s"] > 0
-    assert 0.3 < d["overlap_fraction"] <= 1.0
+    assert d["chunks"] == 8
+    assert d["featurize_s"] >= 8 * sleeps["prepare_sleep"]
+    assert d["collect_s"] >= 8 * sleeps.get("collect_sleep", 0.0)
+    assert d["dispatch_s"] >= 8 * sleeps.get("dispatch_sleep", 0.0)
+    assert 0.0 < d["feature_wait_s"] <= d["featurize_s"]
+    if hidden:
+        assert d["overlap_fraction"] > 0.5
+    else:
+        assert d["overlap_fraction"] < 0.3
     assert eng.stats.overlap_fraction == pytest.approx(
         d["overlap_fraction"], abs=1e-3)
 
 
 def test_single_chunk_call_never_overlaps():
-    """One chunk = nothing to hide behind: the serial path runs and the
-    overlap timers stay zero."""
+    """One chunk = nothing to hide behind: the serial path runs, nothing
+    waits for a worker, and the overlap fraction is zero."""
     eng = SurrogateEngine(_fake_pipeline(), chunk_size=64)
     eng(_configs(10))
     d = eng.stats.as_dict()
     assert d["chunks"] == 1
-    assert d["overlapped_s"] == 0.0
+    assert d["feature_wait_s"] == 0.0
     assert d["overlap_fraction"] == 0.0
 
 
@@ -146,7 +158,7 @@ def test_overlap_prepare_failure_propagates_like_serial():
     """A deterministic featurization error must raise identically with
     and without the pipeline (the worker forwards it, the fallback hits
     it again)."""
-    def bad_prepare(configs):
+    def bad_prepare(configs, stats=None):
         raise ValueError("bad feature table")
 
     pb = PipelinedBackend(bad_prepare, lambda x: x, _rows_for)
