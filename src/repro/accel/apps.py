@@ -583,6 +583,32 @@ def _check_lut_guards(app: AccelDef, guard_meta, guards) -> None:
                 f"APP_LUT_DOMAINS override for {app.name!r})")
 
 
+def batch_labeler(app: AccelDef, entries: Dict[str, Sequence]):
+    """``(fn, guard_meta)``: the compiled labeler of `app` over `entries`
+    (`_batch_label_fn`). A caller that labels many batches resolves it
+    once: the cache lookup hashes and compares every library entry."""
+    return _batch_label_fn(app.name, _entries_items(app, entries))
+
+
+def _padded_chunks(C: np.ndarray, chunk: int):
+    """Yield ``(lo, take, block)`` over ``C`` in chunks of at most `chunk`
+    rows. Ragged blocks are padded up to a power-of-two bucket (capped at
+    the chunk size) with a repeated row, so the jit cache holds at most
+    log2(chunk)+1 model shapes no matter what batch sizes callers send —
+    same policy as the engine's fixed-shape chunking; ``block[:take]``
+    are the real rows."""
+    for lo in range(0, C.shape[0], chunk):
+        Cc = C[lo:lo + chunk]
+        take = Cc.shape[0]
+        bucket = 1
+        while bucket < take:
+            bucket <<= 1
+        bucket = min(bucket, chunk)
+        if take < bucket:
+            Cc = np.concatenate([Cc, np.repeat(Cc[-1:], bucket - take, 0)])
+        yield lo, take, Cc
+
+
 def accuracy_ssim_batch(app: AccelDef, entries: Dict[str, Sequence],
                         configs, images: jax.Array,
                         exact_out: jax.Array | None = None, *,
@@ -591,30 +617,50 @@ def accuracy_ssim_batch(app: AccelDef, entries: Dict[str, Sequence],
 
     ``configs`` is a (B, n_units) int block of library-entry indices (the
     `dataset.sample_configs` layout). Images are evaluated through the
-    config-batched functional model in fixed-size chunks (the ragged tail
-    padded with a repeated row and sliced, so the jit cache holds one
-    shape).
+    config-batched functional model in fixed-size chunks
+    (`ssim_batch_on_device`); once every chunk is dispatched the LUT
+    guards are checked and the scores read back.
     """
     if exact_out is None:
         exact_out = app.run(make_impls(app, exact_choice(app)), images)
-    fn, guard_meta = _batch_label_fn(app.name, _entries_items(app, entries))
+    (scores,), check = ssim_batch_on_device(
+        app, batch_labeler(app, entries), configs, [(images, exact_out)],
+        chunk=chunk)
+    check()
+    return np.asarray(scores, np.float64)
+
+
+def ssim_batch_on_device(app: AccelDef, labeler, configs,
+                         image_sets: Sequence[Tuple[jax.Array, jax.Array]],
+                         *, chunk: int = 256
+                         ) -> Tuple[Tuple[jax.Array, ...], Callable[[], None]]:
+    """SSIM of a config block against several image sets, left on the
+    device: the compiled programs of ``labeler`` (`batch_labeler`) on
+    padded chunks (`_padded_chunks`), each chunk sent to the device once
+    for all the ``(images, exact_out)`` sets, nothing read back.
+
+    Returns one (B,) float32 device array of SSIM scores per image set,
+    and ``check()``, which reads the LUT guards and raises
+    `LutDomainError` if any unit left its table's domain; call it before
+    trusting the scores. `accuracy_ssim_batch` is this with one image set,
+    read back.
+    """
+    fn, guard_meta = labeler
     C = np.asarray(configs, np.int32).reshape(len(configs), -1)
-    B = C.shape[0]
-    out = np.empty(B, np.float64)
-    for lo in range(0, B, chunk):
-        Cc = C[lo:lo + chunk]
-        take = Cc.shape[0]
-        # ragged batches are padded up to a power-of-two bucket (capped at
-        # the chunk size) and sliced, so the jit cache holds at most
-        # log2(chunk)+1 model shapes no matter what batch sizes callers
-        # send — same policy as the engine's fixed-shape chunking
-        bucket = 1
-        while bucket < take:
-            bucket <<= 1
-        bucket = min(bucket, chunk)
-        if take < bucket:
-            Cc = np.concatenate([Cc, np.repeat(Cc[-1:], bucket - take, 0)])
-        scores, guards = fn(jnp.asarray(Cc), images, exact_out)
-        _check_lut_guards(app, guard_meta, guards)
-        out[lo:lo + take] = np.asarray(scores)[:take]
-    return out
+    parts: List[List[jax.Array]] = [[] for _ in image_sets]
+    guards = []
+    for _, take, Cc in _padded_chunks(C, chunk):
+        Cd = jax.device_put(Cc)
+        for part, (images, exact_out) in zip(parts, image_sets):
+            scores, g = fn(Cd, images, exact_out)
+            part.append(scores if take == scores.shape[0] else scores[:take])
+            guards.append(g)
+
+    def check() -> None:
+        # one fetch of every chunk's guards: their copies overlap, where
+        # one read per guard would wait a round trip each
+        for g in jax.device_get(guards):
+            _check_lut_guards(app, guard_meta, g)
+
+    return tuple(p[0] if len(p) == 1 else jnp.concatenate(p)
+                 for p in parts), check

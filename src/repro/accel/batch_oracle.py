@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Sequence, Tuple
 
 import networkx as nx
 import numpy as np
@@ -266,26 +266,56 @@ def timing_batch(app: apps_lib.AccelDef, entries: Dict[str, Sequence],
             "crit": creq > -1e29, "tmax": tmax, "node_ids": ca.node_ids}
 
 
+class DeviceProbe(NamedTuple):
+    """`probe_batch` left on the device, for a consumer that runs there
+    (the GNN engine's forward): ``ssim`` holds one (B,) float32 device
+    array per scale in `apps.PROBE_SIZES` (the probe distortion is
+    ``1 - ssim``), and ``check()`` reads the LUT guards and raises
+    `apps.LutDomainError` as `probe_batch` would."""
+    ssim: Tuple[Any, ...]
+    check: Callable[[], None]
+
+
+class DeviceProber:
+    """`probe_batch` without the read-back, for one accelerator and
+    library: the same compiled programs on the same probe images
+    (`apps.ssim_batch_on_device`), dispatched for every scale; nothing
+    waits for the device. The labeler and the probe images are resolved
+    once, at construction: per batch that lookup would cost more than
+    the dispatch."""
+
+    def __init__(self, app: apps_lib.AccelDef,
+                 entries: Dict[str, Sequence], chunk: int = 1024):
+        self._app, self._chunk = app, chunk
+        self._labeler = apps_lib.batch_labeler(app, entries)
+        self._image_sets = tuple(apps_lib.probe_inputs(app.name, size)
+                                 for size in apps_lib.PROBE_SIZES)
+
+    def __call__(self, configs) -> DeviceProbe:
+        C = np.asarray(configs, np.int64).reshape(
+            -1, len(self._app.unit_nodes))
+        return DeviceProbe(*apps_lib.ssim_batch_on_device(
+            self._app, self._labeler, C, self._image_sets,
+            chunk=self._chunk))
+
+
 def probe_batch(app: apps_lib.AccelDef, entries: Dict[str, Sequence],
                 configs, chunk: int = 1024) -> Dict[str, np.ndarray]:
     """Functional-probe distortion columns for a config block.
 
-    Runs the config-batched functional model (`apps.accuracy_ssim_batch`)
-    on the tiny deterministic probe images (`apps.probe_inputs`, one per
-    scale in `apps.PROBE_SIZES`) and returns ``{probe_err8, probe_err16:
-    (B,) float64}`` where each value is 1 - SSIM vs the exact design.
-    Graph-level features: `dataset.ConfigFeaturizer` broadcasts them
-    across nodes. The compiled labeler is shared with dataset labeling
-    (`_batch_label_fn` lru cache), so the probe adds one extra jit shape,
-    not a second model."""
-    C = np.asarray(configs, np.int64).reshape(-1, len(app.unit_nodes))
-    out = {}
-    for size in apps_lib.PROBE_SIZES:
-        inp, exact_out = apps_lib.probe_inputs(app.name, size)
-        s = apps_lib.accuracy_ssim_batch(app, entries, C, inp, exact_out,
-                                         chunk=chunk)
-        out[f"probe_err{size}"] = 1.0 - s
-    return out
+    Runs the config-batched functional model (`apps.accuracy_ssim_batch`'s
+    compiled programs, through `DeviceProber`) on the tiny deterministic
+    probe images (`apps.probe_inputs`, one per scale in
+    `apps.PROBE_SIZES`), checks the LUT guards and returns ``{probe_err8,
+    probe_err16: (B,) float64}`` where each value is 1 - SSIM vs the
+    exact design. Graph-level features: `dataset.ConfigFeaturizer`
+    broadcasts them across nodes. The compiled labeler is shared with
+    dataset labeling (`_batch_label_fn` lru cache), so the probe adds one
+    extra jit shape, not a second model."""
+    probe = DeviceProber(app, entries, chunk)(configs)
+    probe.check()
+    return {f: 1.0 - np.asarray(s, np.float64)
+            for f, s in zip(apps_lib.PROBE_FIELDS, probe.ssim)}
 
 
 def crit_sets(rep: Dict[str, np.ndarray]) -> List[set]:

@@ -334,12 +334,15 @@ class ConfigFeaturizer:
         self._entries = entries
         self.dynamic = dynamic and bool(self.schema.dynamic_fields)
         self._members: Optional[List[np.ndarray]] = None
-        # `normalized`/`dynamic_raw` run on the engine's featurize worker
+        # `normalized_on_device` runs on the engine's featurize worker
         # thread (the overlap pipeline) while other engines sharing this
         # featurizer (`featurizer_for` caches per dataset) may call it
         # concurrently; the lock makes the lazy member-index build
         # single-shot instead of merely idempotent
         self._members_lock = threading.Lock()
+        # the probe left on the device (`normalized_on_device`), built on
+        # first use; two threads may each build one, and either serves
+        self._prober = None
         choice0 = {n.id: entries[n.kind][0] for n in app.unit_nodes}
         xf0 = graph_lib.node_features(g, app, choice0, crit_nodes=None,
                                       schema=self.schema)
@@ -383,35 +386,46 @@ class ConfigFeaturizer:
                 self._members = members
             return self._members
 
-    def dynamic_raw(self, C: np.ndarray, stats=None) -> np.ndarray:
-        """(B, n_graph_nodes, n_dyn) float32 dynamic timing features.
+    @property
+    def _has_probe(self) -> bool:
+        """Whether this featurizer fills the functional-probe columns."""
+        return self.dynamic and any(f in apps_lib.PROBE_FIELDS
+                                    for f in self.schema.dynamic_fields)
+
+    def dynamic_raw(self, C: np.ndarray) -> np.ndarray:
+        """(B, n_graph_nodes, n_dyn) float32 dynamic timing features, for
+        the build path (`raw`).
 
         One `batch_oracle.timing_batch` sweep per batch, reduced onto the
         (possibly merged) graph nodes per `graph.DYNAMIC_REDUCE` and
-        log1p-compressed where the schema says so — the single source of
-        the dynamic columns for BOTH the build path (`raw`) and the DSE
-        hot path (`normalized`), which is what makes them bit-identical.
-
-        The sweep and the probe run in the profiler spans
-        ``featurize.timing`` and ``featurize.probe``; an engine's
-        `EngineStats` passed as ``stats`` also adds their durations to its
-        ``timing_s`` and ``probe_s``.
+        log1p-compressed where the schema says so (`_dynamic_block`, which
+        the DSE hot path `normalized_on_device` shares), and the blocking
+        `batch_oracle.probe_batch` (the same compiled programs as the hot
+        path's `batch_oracle.DeviceProber`). The sweep and the probe run in
+        the profiler spans ``featurize.timing`` and ``featurize.probe``.
         """
         from repro.accel import batch_oracle
-        fields = self.schema.dynamic_fields
-        with _span(stats, "featurize.timing", "timing_s"):
+        with jax.profiler.TraceAnnotation("featurize.timing"):
             rep = batch_oracle.timing_batch(self._app, self._entries, C)
-        if any(f in apps_lib.PROBE_FIELDS for f in fields):
-            with _span(stats, "featurize.probe", "probe_s"):
+        if self._has_probe:
+            with jax.profiler.TraceAnnotation("featurize.probe"):
                 rep.update(batch_oracle.probe_batch(self._app,
                                                     self._entries, C))
+        return self._dynamic_block(rep, C.shape[0])
+
+    def _dynamic_block(self, rep: Dict[str, np.ndarray],
+                       B: int) -> np.ndarray:
+        """The dynamic columns from the oracle's report ``rep``; a probe
+        field missing from ``rep`` (left on the device) stays 0."""
+        fields = self.schema.dynamic_fields
         members = self._member_index()
-        out = np.empty((C.shape[0], self.n_nodes, len(fields)), np.float32)
+        out = np.zeros((B, self.n_nodes, len(fields)), np.float32)
         for f_idx, f in enumerate(fields):
             if f in apps_lib.PROBE_FIELDS:
                 # graph-level probe distortion: one value per config,
                 # broadcast across nodes (padding rows stay base-valued)
-                out[:, :, f_idx] = rep[f][:, None]
+                if f in rep:
+                    out[:, :, f_idx] = rep[f][:, None]
                 continue
             col = rep[f]                             # (B, n_app_nodes)
             take_min = graph_lib.DYNAMIC_REDUCE[f] == "min"
@@ -456,7 +470,43 @@ class ConfigFeaturizer:
 
     def normalized(self, configs, stats=None) -> np.ndarray:
         """(B, n_pad, F) features normalized with the dataset stats
-        (``stats``: the engine's `EngineStats`, see `dynamic_raw`)."""
+        (``stats``: the engine's `EngineStats`, into whose ``timing_s``
+        and ``probe_s`` the ``featurize.timing`` and ``featurize.probe``
+        spans add their durations).
+
+        `normalized_on_device` with the probe read back: its LUT guards
+        checked and its SSIM standardized into the probe columns on the
+        host, by the float32 expression of `probe_columns`. Together with
+        the float32 cast + elementwise standardization of the other
+        dynamic columns, that is what the build path applies to the whole
+        raw tensor -> bit-identical rows. The read-back is a second
+        ``featurize.probe`` span, after the timing sweep.
+        """
+        X, probe = self.normalized_on_device(configs, stats)
+        if probe.ssim:
+            with _span(stats, "featurize.probe", "probe_s"):
+                probe.check()
+                for (col, mu, sd), s in zip(self.probe_columns(),
+                                            probe.ssim):
+                    X[:, :self.n_nodes, col] = \
+                        (((1 - np.asarray(s)) - mu) / sd)[:, None]
+        return X
+
+    def normalized_on_device(self, configs, stats=None):
+        """`normalized` for a consumer that runs on the device (the GNN
+        engine's forward): the functional probe is dispatched there and
+        its result never read back.
+
+        Returns ``(X, probe)``. ``X`` equals `normalized` in every column
+        but the probe's (their real graph rows hold a placeholder);
+        ``probe`` is the `batch_oracle.DeviceProbe` whose SSIM the
+        consumer standardizes into those columns (`probe_columns`) and
+        whose ``check()`` it runs before trusting its output. The probe is
+        dispatched before the timing sweep, so the device runs it while
+        the host sweeps; its ``featurize.probe`` span (``probe_s``) covers
+        building the config block and dispatching.
+        """
+        from repro.accel import batch_oracle
         if self._norm is None:
             raise RuntimeError("call set_norm(x_mean, x_std) first")
         base, tables, mu_d, sd_d = self._norm
@@ -464,12 +514,32 @@ class ConfigFeaturizer:
         X = np.broadcast_to(base, (C.shape[0],) + base.shape).copy()
         for j, gj in enumerate(self.gidx):
             X[:, gj, self._us] = tables[j][C[:, j]]
+        probe = batch_oracle.DeviceProbe((), lambda: None)
+        if self._has_probe:
+            with _span(stats, "featurize.probe", "probe_s"):
+                if self._prober is None:
+                    self._prober = batch_oracle.DeviceProber(self._app,
+                                                             self._entries)
+                probe = self._prober(C)
         if self.dynamic:
-            # same float32 cast + elementwise standardization the build
-            # path applies to the whole raw tensor -> bit-identical rows
+            with _span(stats, "featurize.timing", "timing_s"):
+                rep = batch_oracle.timing_batch(self._app, self._entries, C)
             X[:, :self.n_nodes, self.schema.dynamic_slice] = \
-                (self.dynamic_raw(C, stats) - mu_d) / sd_d
-        return X
+                (self._dynamic_block(rep, C.shape[0]) - mu_d) / sd_d
+        return X, probe
+
+    def probe_columns(self) -> Tuple[Tuple[int, np.float32, np.float32],
+                                     ...]:
+        """``(column, mean, std)`` of each probe field, in
+        `apps.PROBE_SIZES` order: the float32 standardization `normalized`
+        applies, ``((1 - ssim) - mean) / std``. Empty when this featurizer
+        fills no probe columns."""
+        if not self._has_probe:
+            return ()
+        fields = self.schema.dynamic_fields
+        _, _, mu_d, sd_d = self._norm
+        return tuple((self.schema.col("timing", f), mu_d[fields.index(f)],
+                      sd_d[fields.index(f)]) for f in apps_lib.PROBE_FIELDS)
 
 
 def _span(stats, name: str, counter: str):

@@ -25,9 +25,11 @@ this module provides the production implementation of that callable:
     * **featurize/compute overlap** — the GNN backends are
       `PipelinedBackend`s (prepare → dispatch → collect); with ≥ 2 chunks
       a worker thread featurizes chunk *k+1* on the host (the schema-v2
-      timing sweep + functional probe) while chunk *k* executes on
-      device, and host transfers are deferred until every chunk is in
-      flight — the LM decode-pipelining idiom. ``stats.overlap_fraction``
+      timing sweep; the functional probe is only dispatched, and its
+      result is spliced into the features inside the forward's own
+      program) while chunk *k* executes on device, and host transfers
+      are deferred until every chunk is in flight — the LM
+      decode-pipelining idiom. ``stats.overlap_fraction``
       reports the share of featurization the calling thread did not wait
       for;
     * **config-key memoization** — NSGA-II/III re-evaluations of surviving
@@ -62,7 +64,8 @@ import threading
 import time
 import warnings
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 import numpy as np
 
@@ -137,8 +140,19 @@ class EngineStats:
                       the share of ``featurize_s`` it did not wait for.
         timing_s:     the part of ``featurize_s`` in the timing sweep
                       (`batch_oracle.timing_batch`).
-        probe_s:      the part of ``featurize_s`` in the functional probe
-                      (`batch_oracle.probe_batch`).
+        probe_s:      the part of ``featurize_s`` in the functional
+                      probe. On the GNN backends' path
+                      (`ConfigFeaturizer.normalized_on_device`) that is
+                      building the config block and dispatching the
+                      probe, whose result stays on the device; where
+                      features are made on the host
+                      (`ConfigFeaturizer.normalized`), that dispatch and,
+                      after the timing sweep, the wait to read it back.
+        probe_on_device: chunks the prefetch worker featurized whose
+                      probe result stayed on the device, spliced into the
+                      forward in its own program (equals ``chunks`` on a
+                      pipelined GNN call; 0 where the probe is read back
+                      on the host or there is none).
         memo_s:       memo key building and lookup, plus cache insertion,
                       eviction and row assembly.
 
@@ -168,6 +182,7 @@ class EngineStats:
     timing_s: float = 0.0
     probe_s: float = 0.0
     memo_s: float = 0.0
+    probe_on_device: int = 0
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -249,7 +264,8 @@ class EngineStats:
                     "feature_wait_s": round(self.feature_wait_s, 4),
                     "timing_s": round(self.timing_s, 4),
                     "probe_s": round(self.probe_s, 4),
-                    "memo_s": round(self.memo_s, 4)}
+                    "memo_s": round(self.memo_s, 4),
+                    "probe_on_device": self.probe_on_device}
             overlap = self.overlap_fraction
         snap["cache_hit_rate"] = round(
             snap["cache_hits"] / snap["configs"], 4) if snap["configs"] \
@@ -291,10 +307,28 @@ class _ConfigFeaturizer:
         self.sizes = feat.sizes
         self.adj = feat.adj                                # (N, N) normalized
         self.mask = feat.mask                              # (N,)
+        self.n_nodes = feat.n_nodes
+        self.probe_columns = feat.probe_columns
 
     def __call__(self, configs: Sequence[Config],
                  stats: Optional[EngineStats] = None) -> np.ndarray:
         return self._feat.normalized(configs, stats)
+
+    def on_device(self, configs: Sequence[Config],
+                  stats: Optional[EngineStats] = None):
+        """``(X, probe)``: host features with the functional probe left on
+        the device (`_featurize_on_device`)."""
+        return _featurize_on_device(self._feat, configs, stats)
+
+
+def _featurize_on_device(feat, configs: Sequence[Config],
+                         stats: Optional[EngineStats] = None):
+    """``feat.normalized_on_device(configs, stats)``, counting the chunk
+    in ``stats.probe_on_device`` when its probe stayed on the device."""
+    X, probe = feat.normalized_on_device(configs, stats)
+    if stats is not None and probe.ssim:
+        stats.update(probe_on_device=1)
+    return X, probe
 
 
 # --------------------------------------------------------------------------
@@ -312,7 +346,9 @@ class PipelinedBackend:
 
     * ``prepare(configs, stats=None) -> X`` — host-side featurization
       (NumPy table lookup plus, under schema v2, the batched timing sweep
-      and the tiny-image functional probe). Runs on the prefetch worker
+      and the tiny-image functional probe; the GNN constructors leave the
+      probe's result on the device, so their ``X`` is the host features
+      with a `batch_oracle.DeviceProbe`). Runs on the prefetch worker
       thread. The engine passes its `EngineStats`, into whose
       ``timing_s``/``probe_s`` the featurizer counts its parts.
     * ``dispatch(X) -> handle`` — hand the features to the device and
@@ -321,7 +357,8 @@ class PipelinedBackend:
       dispatching while earlier chunks execute. With ``devices > 1`` the
       GNN constructors put each chunk on the next device here.
     * ``collect(handle) -> (B, n_obj) ndarray`` — block on the device
-      result, transfer, and post-process (denormalize, ssim flip).
+      result, transfer, and post-process (denormalize, ssim flip). The
+      GNN constructors check the probe's LUT guards here first.
 
     ``devices`` records, for `EngineStats`, how many devices the chunks
     are spread over.
@@ -364,7 +401,9 @@ def _over_devices(fn, n_devices: int):
     devices in turn; ``fn`` itself when the cap is 1 (single-device
     engines never touch device placement).
 
-    A call's rows stay together on one device, which runs the
+    The call's argument may be a pytree (the GNN backends pass the host
+    features with the device probe's SSIM vectors); all of it moves to
+    the device. A call's rows stay together on one device, which runs the
     single-device program at the single-device shape. Splitting a chunk
     instead would change its rows: their last bits depend on the program
     XLA compiles around them (on a TPU the pure-JAX forward's node-axis
@@ -399,9 +438,52 @@ def _over_devices(fn, n_devices: int):
 KERNEL_PARITY_TOL = 1e-4     # rtol and atol, on normalized targets
 
 
+def _no_splice(X):
+    return X
+
+
+def _probe_splice(feat) -> Callable:
+    """The first step of every GNN backend's forward, traced into its
+    program: ``(X, ssim) -> X`` with each device probe scale's
+    standardized distortion ``((1 - s) - mean) / std`` written into its
+    probe column of the real graph rows (``feat.probe_columns()``), the
+    float32 expression `ConfigFeaturizer.normalized` applies on the host;
+    padding rows keep `normalized`'s values. A forward made without it
+    (`_no_splice`) takes plain features."""
+    import jax
+
+    cols, n = feat.probe_columns(), feat.n_nodes
+
+    def splice(inputs):
+        X, ssim = inputs
+        for (col, mu, sd), s in zip(cols, ssim):
+            # the barrier hides the constants from XLA's simplifier, which
+            # would otherwise fold them into (1 - mu) - s and a multiply
+            # by 1/sd: far from the host's bits where the column is near 0
+            mu, sd = jax.lax.optimization_barrier((mu, sd))
+            X = X.at[:, :n, col].set((((1 - s) - mu) / sd)[:, None])
+        return X
+
+    return splice
+
+
+class _Dispatched(NamedTuple):
+    """A GNN backend's dispatched chunk: the forward's device output
+    (``out``) and the probe's LUT-guard ``check``, which ``collect`` runs
+    before it reads the rows."""
+    out: Any
+    check: Callable[[], None]
+
+    def devices(self):
+        """The devices holding the output (`jax.Array.devices`)."""
+        import jax
+        return set().union(*(a.devices() for a in jax.tree.leaves(self.out)))
+
+
 def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
-                      mask_row: np.ndarray):
-    """jit'd X -> normalized (B, 4) targets via `models.predict`."""
+                      mask_row: np.ndarray, splice: Callable = _no_splice):
+    """jit'd X -> normalized (B, 4) targets via `models.predict`; ``X`` is
+    first passed through ``splice`` (`_probe_splice`)."""
     import jax
     import jax.numpy as jnp
     from repro.core import models
@@ -411,6 +493,7 @@ def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
 
     @jax.jit
     def f(X):
+        X = splice(X)
         B = X.shape[0]
         adj = jnp.broadcast_to(A, (B,) + A.shape)
         mask = jnp.broadcast_to(m, (B,) + m.shape)
@@ -421,8 +504,10 @@ def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
 
 
 def _make_kernel_predict(two_cfg, params, adj_row: np.ndarray,
-                         mask_row: np.ndarray, graph_block: int = 8):
-    """jit'd X -> normalized (B, 4), message passing via Pallas `gnn_mp`.
+                         mask_row: np.ndarray, graph_block: int = 8,
+                         splice: Callable = _no_splice):
+    """jit'd X -> normalized (B, 4), message passing via Pallas `gnn_mp`;
+    ``X`` is first passed through ``splice`` (`_probe_splice`).
 
     Supports the gcn and gsae architectures, whose layer update is exactly
     the kernel's fused ``relu(A' @ (H @ Wn) + H @ Ws + b)`` with
@@ -485,7 +570,7 @@ def _make_kernel_predict(two_cfg, params, adj_row: np.ndarray,
     @jax.jit
     def f(X):
         with jax.default_matmul_precision("highest"):
-            return forward(X)
+            return forward(splice(X))
 
     return f
 
@@ -1044,7 +1129,9 @@ class SurrogateEngine:
                 f"model was trained on feature schema v{sv} but the "
                 f"dataset featurizes with v{feat.schema.version} — "
                 f"rebuild the stale artifact")
-        jax_predict = _make_jax_predict(two_cfg, params, feat.adj, feat.mask)
+        splice = _probe_splice(feat)
+        jax_predict = _make_jax_predict(two_cfg, params, feat.adj, feat.mask,
+                                        splice)
         predict, backend = jax_predict, "jax"
         kernel_arch = two_cfg.gnn.arch in ("gcn", "gsae")
         if use_kernel == "on" and not kernel_arch:
@@ -1053,9 +1140,12 @@ class SurrogateEngine:
                 f"arch={two_cfg.gnn.arch!r} (only gcn/gsae)")
         if use_kernel == "on" or (use_kernel == "auto" and kernel_arch
                                   and kernel_ops.on_tpu()):
-            kp = _make_kernel_predict(two_cfg, params, feat.adj, feat.mask)
-            Xp = feat(_probe_configs(feat.sizes))
-            got, want = np.asarray(kp(Xp)), np.asarray(jax_predict(Xp))
+            kp = _make_kernel_predict(two_cfg, params, feat.adj, feat.mask,
+                                      splice=splice)
+            Xp, probe = feat.on_device(_probe_configs(feat.sizes))
+            probe.check()
+            inputs = (Xp, probe.ssim)
+            got, want = np.asarray(kp(inputs)), np.asarray(jax_predict(inputs))
             if not np.allclose(got, want, rtol=KERNEL_PARITY_TOL,
                                atol=KERNEL_PARITY_TOL):
                 raise RuntimeError(
@@ -1065,13 +1155,19 @@ class SurrogateEngine:
             predict, backend = kp, "pallas"
 
         n_dev = _resolve_devices(devices)
-        dispatch = _over_devices(predict, n_dev)
+        on_devices = _over_devices(predict, n_dev)
 
         def prepare(configs, stats=None):
-            return feat(configs, stats)     # host: lookup + dynamic sweep
+            # host: lookup + timing sweep; the probe stays on the device
+            return feat.on_device(configs, stats)
 
-        def collect(y_dev):
-            y = np.asarray(y_dev)           # blocks on device compute
+        def dispatch(features):
+            X, probe = features
+            return _Dispatched(on_devices((X, probe.ssim)), probe.check)
+
+        def collect(h):
+            h.check()                       # the probe's LUT guards
+            y = np.asarray(h.out)           # blocks on device compute
             y = ds.denorm_y(y)
             y[:, 3] = 1 - y[:, 3]           # ssim -> 1-ssim (minimize)
             return y
@@ -1114,22 +1210,28 @@ class SurrogateEngine:
                                        merged.n_pad, schema=ds.schema)
         feat.set_norm(ds.x_mean, ds.x_std)
         block = graph_lib.app_block(app_name, feat.mask)      # (N, A)
+        # the probe columns lie before the app block, so the app's own
+        # featurizer places them in the merged layout too
         jax_predict = _make_jax_predict(two_cfg, params, feat.adj,
-                                        feat.mask)
+                                        feat.mask, _probe_splice(feat))
         n_dev = _resolve_devices(devices)
         on_devices = _over_devices(jax_predict, n_dev)
 
         def prepare(configs, stats=None):
-            X = feat.normalized(configs, stats)
+            X, probe = _featurize_on_device(feat, configs, stats)
             return np.concatenate(
                 [X, np.broadcast_to(block, (X.shape[0],) + block.shape)],
-                axis=-1)
+                axis=-1), probe
 
-        def dispatch(Xa):
-            return on_devices(np.ascontiguousarray(Xa))
+        def dispatch(features):
+            Xa, probe = features
+            return _Dispatched(
+                on_devices((np.ascontiguousarray(Xa), probe.ssim)),
+                probe.check)
 
-        def collect(y_dev):
-            y = np.asarray(y_dev)
+        def collect(h):
+            h.check()                       # the probe's LUT guards
+            y = np.asarray(h.out)
             y = ds.denorm_y(y)
             y[:, 3] = 1 - y[:, 3]           # ssim -> 1-ssim (minimize)
             return y
@@ -1165,11 +1267,13 @@ class SurrogateEngine:
         feat = _ConfigFeaturizer(ds, app, entries)
         A = jnp.asarray(feat.adj)
         m_row = jnp.asarray(feat.mask)
+        splice = _probe_splice(feat)
 
         group_fns = []
         for g_cfg, params in ens.groups:
             @jax.jit
             def gf(X, g_cfg=g_cfg, params=params):
+                X = splice(X)
                 B = X.shape[0]
                 adj = jnp.broadcast_to(A, (B,) + A.shape)
                 mask = jnp.broadcast_to(m_row, (B,) + m_row.shape)
@@ -1180,14 +1284,19 @@ class SurrogateEngine:
 
         n_obj = len(models_lib.TARGETS)
         n_dev = _resolve_devices(devices)
-        dispatch = _over_devices(lambda X: [gf(X) for gf in group_fns],
-                                 n_dev)
+        on_devices = _over_devices(
+            lambda inputs: [gf(inputs) for gf in group_fns], n_dev)
 
         def prepare(configs, stats=None):
-            return feat(configs, stats)
+            return feat.on_device(configs, stats)
 
-        def collect(handles):
-            Y = np.concatenate([np.asarray(h) for h in handles], 0)
+        def dispatch(features):
+            X, probe = features
+            return _Dispatched(on_devices((X, probe.ssim)), probe.check)
+
+        def collect(h):
+            h.check()                       # the probe's LUT guards
+            Y = np.concatenate([np.asarray(a) for a in h.out], 0)
             mean = ds.denorm_y(Y.mean(0))
             std = Y.std(0) * np.asarray(ds.y_std)
             mean[:, 3] = 1 - mean[:, 3]     # ssim -> 1-ssim (minimize)
