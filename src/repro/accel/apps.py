@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -458,8 +458,23 @@ class LutDomainError(RuntimeError):
 def lut_gather(table: jax.Array, a: jax.Array, b: jax.Array,
                wb: int) -> jax.Array:
     """Read a truth table at ``(a << wb) | b``: one XLA gather (the
-    labeler's LUT path on every platform)."""
-    return jnp.take(table, (a << wb) | b, axis=0)
+    labeler's LUT path on every platform), under the name scope
+    ``lut_gather``, which the compiled ops carry in their metadata."""
+    with jax.named_scope("lut_gather"):
+        return jnp.take(table, (a << wb) | b, axis=0)
+
+
+class Labeler(NamedTuple):
+    """The compiled labeler of one app over one library (`batch_labeler`).
+
+    ``fn(C, images, exact_out) -> ((B,) ssim, guards)``; ``guard_meta``
+    maps each guard tag to its unit kind and LUT domain; both are filled
+    when the model is traced. ``lut_reads(images)`` is the number of
+    table entries one configuration's model gathers on ``images``,
+    counted while tracing it."""
+    fn: Callable
+    guard_meta: Dict[str, Tuple[str, int, int]]
+    lut_reads: Callable[[jax.Array], int]
 
 
 def _entries_items(app: AccelDef, entries: Dict[str, Sequence]
@@ -470,14 +485,16 @@ def _entries_items(app: AccelDef, entries: Dict[str, Sequence]
 
 
 @functools.lru_cache(maxsize=64)
-def _batch_label_fn(app_name: str, entries_items):
+def _batch_label_fn(app_name: str, entries_items) -> Labeler:
     """Compiled labeler: (C (B,U) int32, images, exact_out) -> ((B,) ssim,
     guard dict); two jitted stages (vmapped functional model, vmapped
-    SSIM). `guard_meta` maps guard tags to LUT domains; it is filled at
-    trace time and read by the caller to validate table coverage."""
+    SSIM). `guard_meta` maps guard tags to LUT domains, read by the
+    caller to validate table coverage; it and the table reads per
+    configuration and image shape are filled at trace time (`Labeler`)."""
     app = APPS[app_name]
     entries = dict(entries_items)
     guard_meta: Dict[str, Tuple[str, int, int]] = {}
+    reads: Dict[Tuple[int, ...], int] = {}
 
     node_data = []
     for node in app.unit_nodes:
@@ -492,7 +509,7 @@ def _batch_label_fn(app_name: str, entries_items):
             node_data.append(("analytic", node, kind, jnp.asarray(fam),
                               jnp.asarray(k), jnp.asarray(seg)))
 
-    def _lut_impl(node, kind, ea, eb, table, e, guards, counts):
+    def _lut_impl(node, kind, ea, eb, table, e, guards, counts, n_read):
         unary = kind.op == "sqrt"
 
         def excess(x, bits):
@@ -505,6 +522,7 @@ def _batch_label_fn(app_name: str, entries_items):
             tag = f"{node.id}#{counts.setdefault(node.id, 0)}"
             counts[node.id] += 1
             guard_meta[tag] = (kind.name, ea, eb)
+            n_read[0] += a.size          # one table entry per operand
             zero = jnp.zeros((), jnp.int32)
             if unary:
                 guards[tag] = (excess(a, ea), zero)
@@ -540,19 +558,24 @@ def _batch_label_fn(app_name: str, entries_items):
         return impl
 
     def model_chunk(C, images):
+        n_read = [0]
+
         def model_one(cfg):
             impls, guards, counts = {}, {}, {}
             for j, nd in enumerate(node_data):
                 if nd[0] == "lut":
                     _, node, kind, ea, eb, table = nd
                     impls[node.id] = _lut_impl(node, kind, ea, eb, table,
-                                               cfg[j], guards, counts)
+                                               cfg[j], guards, counts,
+                                               n_read)
                 else:
                     _, node, kind, fam, k, seg = nd
                     impls[node.id] = _analytic_impl(kind, fam, k, seg,
                                                     cfg[j])
             return app.run(impls, images), guards
-        return jax.vmap(model_one)(C)
+        out = jax.vmap(model_one)(C)
+        reads[tuple(images.shape)] = n_read[0]
+        return out
 
     def ssim_chunk(out, exact_out):
         return jax.vmap(lambda o: ssim(o, exact_out))(out)
@@ -565,9 +588,18 @@ def _batch_label_fn(app_name: str, entries_items):
         out, guards = _jit_model(C, images)
         return _jit_ssim(out, exact_out), guards
 
+    def lut_reads(images) -> int:
+        if not any(nd[0] == "lut" for nd in node_data):
+            return 0
+        if tuple(images.shape) not in reads:
+            # trace (never compile) one configuration's model
+            jax.eval_shape(model_chunk, jax.ShapeDtypeStruct(
+                (1, len(node_data)), jnp.int32), images)
+        return reads[tuple(images.shape)]
+
     _jit_model = jax.jit(model_chunk)
     _jit_ssim = jax.jit(ssim_chunk)
-    return run_chunk, guard_meta
+    return Labeler(run_chunk, guard_meta, lut_reads)
 
 
 def _check_lut_guards(app: AccelDef, guard_meta, guards) -> None:
@@ -583,10 +615,10 @@ def _check_lut_guards(app: AccelDef, guard_meta, guards) -> None:
                 f"APP_LUT_DOMAINS override for {app.name!r})")
 
 
-def batch_labeler(app: AccelDef, entries: Dict[str, Sequence]):
-    """``(fn, guard_meta)``: the compiled labeler of `app` over `entries`
-    (`_batch_label_fn`). A caller that labels many batches resolves it
-    once: the cache lookup hashes and compares every library entry."""
+def batch_labeler(app: AccelDef, entries: Dict[str, Sequence]) -> Labeler:
+    """The compiled labeler of `app` over `entries` (`_batch_label_fn`).
+    A caller that labels many batches resolves it once: the cache lookup
+    hashes and compares every library entry."""
     return _batch_label_fn(app.name, _entries_items(app, entries))
 
 
@@ -645,7 +677,7 @@ def ssim_batch_on_device(app: AccelDef, labeler, configs,
     trusting the scores. `accuracy_ssim_batch` is this with one image set,
     read back.
     """
-    fn, guard_meta = labeler
+    fn, guard_meta = labeler.fn, labeler.guard_meta
     C = np.asarray(configs, np.int32).reshape(len(configs), -1)
     parts: List[List[jax.Array]] = [[] for _ in image_sets]
     guards = []

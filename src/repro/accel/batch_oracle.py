@@ -291,6 +291,14 @@ class DeviceProber:
         self._image_sets = tuple(apps_lib.probe_inputs(app.name, size)
                                  for size in apps_lib.PROBE_SIZES)
 
+    @functools.cached_property
+    def lut_reads(self) -> int:
+        """Truth-table entries the probe gathers per configuration, over
+        every scale (`apps.Labeler.lut_reads`; 0 for an app without LUT
+        units)."""
+        return sum(self._labeler.lut_reads(images)
+                   for images, _ in self._image_sets)
+
     def __call__(self, configs) -> DeviceProbe:
         C = np.asarray(configs, np.int64).reshape(
             -1, len(self._app.unit_nodes))

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.accel.units import (ADD8, ADD12, ADD16, KINDS, MUL8, MUL8X4,
-                               SQRT18, SUB10, UnitInstance, UnitKind)
+                               SQRT18, SUB10, UnitInstance, UnitKind, host)
 
 
 # --------------------------------------------------------------------------
@@ -127,24 +127,29 @@ def _inputs_for(kind: UnitKind, max_exhaustive: int = 1 << 20
 @functools.lru_cache(maxsize=None)
 def _char_inputs(kind_name: str):
     a, b = _inputs_for(KINDS[kind_name])
-    return jnp.asarray(a), jnp.asarray(b)
+    with host():
+        return jnp.asarray(a), jnp.asarray(b)
 
 
 def error_metrics(inst: UnitInstance) -> Dict[str, float]:
+    """MAE, MRE, MSE and WCE of `inst` against the exact unit, computed on
+    the host's CPU (`units.host`), as its truth tables are: pruning reads
+    these, so every platform prunes to the same design space."""
     a, b = _char_inputs(inst.kind.name)
-    exact = UnitInstance(inst.kind, "exact", 0).fn()(a, b)
-    approx = inst.fn()(a, b)
-    # float32 on purpose: the repo never enables jax x64, so a float64
-    # astype would silently truncate to f32 anyway (with a warning per
-    # trace); saying f32 keeps values identical and the logs quiet
-    err = (approx - exact).astype(jnp.float32)
-    denom = jnp.maximum(jnp.abs(exact.astype(jnp.float32)), 1.0)
-    return {
-        "mae": float(jnp.mean(jnp.abs(err))),
-        "mre": float(jnp.mean(jnp.abs(err) / denom)),
-        "mse": float(jnp.mean(err ** 2)),
-        "wce": float(jnp.max(jnp.abs(err) / denom)),
-    }
+    with host():
+        exact = UnitInstance(inst.kind, "exact", 0).fn()(a, b)
+        approx = inst.fn()(a, b)
+        # float32 on purpose: the repo never enables jax x64, so a float64
+        # astype would silently truncate to f32 anyway (with a warning per
+        # trace); saying f32 keeps values identical and the logs quiet
+        err = (approx - exact).astype(jnp.float32)
+        denom = jnp.maximum(jnp.abs(exact.astype(jnp.float32)), 1.0)
+        return {
+            "mae": float(jnp.mean(jnp.abs(err))),
+            "mre": float(jnp.mean(jnp.abs(err) / denom)),
+            "mse": float(jnp.mean(err ** 2)),
+            "wce": float(jnp.max(jnp.abs(err) / denom)),
+        }
 
 
 # --------------------------------------------------------------------------
@@ -325,7 +330,8 @@ def stacked_lut(entries: Tuple[LibEntry, ...], ea: int, eb: int
     Entry ``i``'s value for operands (a, b) sits at index
     ``(i << (ea+eb)) | (a << eb) | b``, so folding the per-config library
     choice into the ``a`` operand ``(i << ea) | a`` turns a whole batch of
-    mixed configurations into one gather.
+    mixed configurations into one gather. Built on the host's CPU
+    (`UnitInstance.lut`); the labeler moves it to the device once.
     """
     return np.concatenate(
         [np.asarray(e.inst.lut(ea, eb)) for e in entries])

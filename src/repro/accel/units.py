@@ -52,6 +52,16 @@ SQRT18 = UnitKind("sqrt", 18, 0)
 KINDS = {k.name: k for k in (ADD8, ADD12, ADD16, SUB10, MUL8, MUL8X4, SQRT18)}
 
 
+def host():
+    """Context in which unit functions are evaluated for a truth table or a
+    characterization: the host's CPU, whose float32 arithmetic is IEEE.
+    The float families (mitchell, drum, pwl, newton) truncate results of
+    float32 ``log2``, ``exp2`` and division to integers, so an accelerator
+    that rounds one ulp elsewhere moves a table entry by one; on the host
+    every platform builds the tables the plain reference defines."""
+    return jax.default_device(jax.devices("cpu")[0])
+
+
 def _mask(k: int) -> int:
     return (1 << k) - 1
 
@@ -316,13 +326,16 @@ class UnitInstance:
         values beyond the nominal width (e.g. DCT butterfly sums into the
         mul8x4 port). The unit functions are well defined on the wider
         ints, so the widened table agrees with direct evaluation. Unary
-        sqrt tables use ``eb=0`` -> (2^ea,).
+        sqrt tables use ``eb=0`` -> (2^ea,). Computed on the host's CPU
+        (`host`), where the table stays until a caller moves it.
         """
         ea = self.kind.width_a if ea is None else ea
         eb = self.kind.width_b if eb is None else eb
         fn = self.fn()
-        if self.kind.op == "sqrt":
-            return fn(jnp.arange(1 << ea, dtype=jnp.int32)).astype(jnp.int32)
-        a = jnp.repeat(jnp.arange(1 << ea, dtype=jnp.int32), 1 << eb)
-        b = jnp.tile(jnp.arange(1 << eb, dtype=jnp.int32), 1 << ea)
-        return fn(a, b).astype(jnp.int32)
+        with host():
+            if self.kind.op == "sqrt":
+                return fn(jnp.arange(1 << ea, dtype=jnp.int32)
+                          ).astype(jnp.int32)
+            a = jnp.repeat(jnp.arange(1 << ea, dtype=jnp.int32), 1 << eb)
+            b = jnp.tile(jnp.arange(1 << eb, dtype=jnp.int32), 1 << ea)
+            return fn(a, b).astype(jnp.int32)

@@ -468,6 +468,14 @@ class ConfigFeaturizer:
         sd_d = np.asarray(x_std[dyn], np.float32)
         self._norm = (base, tables, mu_d, sd_d)
 
+    @property
+    def probe_guards(self) -> bool:
+        """Whether the functional probe reads truth tables, and so has
+        LUT-domain guards to check: it runs and the app has a tabulated
+        unit kind (`library.LUT_DOMAINS`)."""
+        return self._has_probe and any(n.kind in lib.LUT_DOMAINS
+                                       for n in self._app.unit_nodes)
+
     def normalized(self, configs, stats=None) -> np.ndarray:
         """(B, n_pad, F) features normalized with the dataset stats
         (``stats``: the engine's `EngineStats`, into whose ``timing_s``
@@ -504,7 +512,10 @@ class ConfigFeaturizer:
         whose ``check()`` it runs before trusting its output. The probe is
         dispatched before the timing sweep, so the device runs it while
         the host sweeps; its ``featurize.probe`` span (``probe_s``) covers
-        building the config block and dispatching.
+        building the config block and dispatching, and its ``lut_reads``
+        argument, added to ``stats.lut_reads``, counts the truth-table
+        entries the probe gathers (`batch_oracle.DeviceProber.lut_reads`
+        per configuration).
         """
         from repro.accel import batch_oracle
         if self._norm is None:
@@ -516,11 +527,15 @@ class ConfigFeaturizer:
             X[:, gj, self._us] = tables[j][C[:, j]]
         probe = batch_oracle.DeviceProbe((), lambda: None)
         if self._has_probe:
-            with _span(stats, "featurize.probe", "probe_s"):
-                if self._prober is None:
-                    self._prober = batch_oracle.DeviceProber(self._app,
-                                                             self._entries)
+            if self._prober is None:
+                self._prober = batch_oracle.DeviceProber(self._app,
+                                                         self._entries)
+            reads = self._prober.lut_reads * C.shape[0]
+            with _span(stats, "featurize.probe", "probe_s",
+                       lut_reads=reads):
                 probe = self._prober(C)
+            if stats is not None:
+                stats.update(lut_reads=reads)
         if self.dynamic:
             with _span(stats, "featurize.timing", "timing_s"):
                 rep = batch_oracle.timing_batch(self._app, self._entries, C)
@@ -542,12 +557,12 @@ class ConfigFeaturizer:
                       sd_d[fields.index(f)]) for f in apps_lib.PROBE_FIELDS)
 
 
-def _span(stats, name: str, counter: str):
-    """``stats.span(name, counter)``, or the bare profiler span when no
-    engine counts this featurization (dataset building)."""
+def _span(stats, name: str, counter: str, **args):
+    """``stats.span(name, counter, **args)``, or the bare profiler span
+    when no engine counts this featurization (dataset building)."""
     if stats is None:
-        return jax.profiler.TraceAnnotation(name)
-    return stats.span(name, counter)
+        return jax.profiler.TraceAnnotation(name, **args)
+    return stats.span(name, counter, **args)
 
 
 def _entries_sig(entries: Dict[str, Sequence]) -> Tuple:

@@ -155,6 +155,15 @@ class EngineStats:
                       on the host or there is none).
         memo_s:       memo key building and lookup, plus cache insertion,
                       eviction and row assembly.
+        guard_s:      the part of ``collect_s`` reading the functional
+                      probe's LUT-domain guards (the pipelined GNN
+                      backends' ``check`` phase; 0 where the probe reads
+                      no truth table).
+        lut_reads:    truth-table entries the functional probes gathered
+                      on the device (LUT applications per pixel x probe
+                      pixels x configurations, counted when the labeler
+                      is traced, never on the device; 0 without LUT
+                      units).
 
     ``wall_time_s`` and the timers from ``featurize_s`` on are summed
     durations of the engine's `span`s (``engine.*`` on the calling
@@ -183,6 +192,8 @@ class EngineStats:
     probe_s: float = 0.0
     memo_s: float = 0.0
     probe_on_device: int = 0
+    guard_s: float = 0.0
+    lut_reads: int = 0
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -265,7 +276,9 @@ class EngineStats:
                     "timing_s": round(self.timing_s, 4),
                     "probe_s": round(self.probe_s, 4),
                     "memo_s": round(self.memo_s, 4),
-                    "probe_on_device": self.probe_on_device}
+                    "probe_on_device": self.probe_on_device,
+                    "guard_s": round(self.guard_s, 4),
+                    "lut_reads": self.lut_reads}
             overlap = self.overlap_fraction
         snap["cache_hit_rate"] = round(
             snap["cache_hits"] / snap["configs"], 4) if snap["configs"] \
@@ -309,6 +322,7 @@ class _ConfigFeaturizer:
         self.mask = feat.mask                              # (N,)
         self.n_nodes = feat.n_nodes
         self.probe_columns = feat.probe_columns
+        self.probe_guards = feat.probe_guards
 
     def __call__(self, configs: Sequence[Config],
                  stats: Optional[EngineStats] = None) -> np.ndarray:
@@ -356,9 +370,13 @@ class PipelinedBackend:
       immediately with a future-like device array, so the engine can keep
       dispatching while earlier chunks execute. With ``devices > 1`` the
       GNN constructors put each chunk on the next device here.
+    * ``check(handle)`` — optional: raise if the dispatched chunk's
+      result cannot be trusted. The GNN constructors read the functional
+      probe's LUT-domain guards here (`_guard_check`); the engine runs it
+      first in ``collect``'s span, in an ``engine.guards`` span of its
+      own.
     * ``collect(handle) -> (B, n_obj) ndarray`` — block on the device
-      result, transfer, and post-process (denormalize, ssim flip). The
-      GNN constructors check the probe's LUT guards here first.
+      result, transfer, and post-process (denormalize, ssim flip).
 
     ``devices`` records, for `EngineStats`, how many devices the chunks
     are spread over.
@@ -367,14 +385,19 @@ class PipelinedBackend:
     def __init__(self, prepare: Callable[[Sequence[Config]], Any],
                  dispatch: Callable[[Any], Any],
                  collect: Callable[[Any], np.ndarray], *,
+                 check: Optional[Callable[[Any], None]] = None,
                  devices: int = 1):
         self.prepare = prepare
         self.dispatch = dispatch
+        self.check = check
         self.collect = collect
         self.devices = max(1, int(devices))
 
     def __call__(self, configs: Sequence[Config]) -> np.ndarray:
-        return self.collect(self.dispatch(self.prepare(configs)))
+        handle = self.dispatch(self.prepare(configs))
+        if self.check is not None:
+            self.check(handle)
+        return self.collect(handle)
 
 
 def _resolve_devices(devices) -> int:
@@ -469,8 +492,8 @@ def _probe_splice(feat) -> Callable:
 
 class _Dispatched(NamedTuple):
     """A GNN backend's dispatched chunk: the forward's device output
-    (``out``) and the probe's LUT-guard ``check``, which ``collect`` runs
-    before it reads the rows."""
+    (``out``) and the probe's LUT-guard ``check``, which the backend's
+    ``check`` phase runs before ``collect`` reads the rows."""
     out: Any
     check: Callable[[], None]
 
@@ -478,6 +501,15 @@ class _Dispatched(NamedTuple):
         """The devices holding the output (`jax.Array.devices`)."""
         import jax
         return set().union(*(a.devices() for a in jax.tree.leaves(self.out)))
+
+
+def _guard_check(feat) -> Optional[Callable[[_Dispatched], None]]:
+    """The GNN backends' ``check`` phase: the functional probe's LUT
+    guards of a dispatched chunk, or None where the probe reads no truth
+    table (``feat.probe_guards``), so there is nothing to read."""
+    if not feat.probe_guards:
+        return None
+    return lambda handle: handle.check()
 
 
 def _make_jax_predict(two_cfg, params, adj_row: np.ndarray,
@@ -1028,7 +1060,9 @@ class SurrogateEngine:
 
         Spans: ``featurize.chunk`` on the worker; ``engine.wait_features``,
         ``engine.dispatch`` and ``engine.collect`` on the calling thread,
-        each with the engine call number and the plan index.
+        each with the engine call number and the plan index; inside
+        ``engine.collect``, ``engine.guards`` where the backend has a
+        ``check`` phase.
         """
         pb, st = self._pipeline, self.stats
         prepared: "queue_lib.Queue" = queue_lib.Queue(maxsize=2)
@@ -1073,6 +1107,10 @@ class SurrogateEngine:
                 y = None
                 if handle is not None:
                     try:
+                        if pb.check is not None:
+                            with st.span("engine.guards", "guard_s",
+                                         call=call, chunk=idx):
+                                pb.check(handle)
                         y = np.asarray(pb.collect(handle))
                     except BaseException:   # noqa: BLE001 — healed below
                         y = None
@@ -1166,13 +1204,13 @@ class SurrogateEngine:
             return _Dispatched(on_devices((X, probe.ssim)), probe.check)
 
         def collect(h):
-            h.check()                       # the probe's LUT guards
             y = np.asarray(h.out)           # blocks on device compute
             y = ds.denorm_y(y)
             y[:, 3] = 1 - y[:, 3]           # ssim -> 1-ssim (minimize)
             return y
 
-        pb = PipelinedBackend(prepare, dispatch, collect, devices=n_dev)
+        pb = PipelinedBackend(prepare, dispatch, collect,
+                              check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend=backend, chunk_size=chunk_size,
                    fixed_shape=True, cache=cache, overlap=overlap,
                    schema_version=sv)
@@ -1230,13 +1268,13 @@ class SurrogateEngine:
                 probe.check)
 
         def collect(h):
-            h.check()                       # the probe's LUT guards
             y = np.asarray(h.out)
             y = ds.denorm_y(y)
             y[:, 3] = 1 - y[:, 3]           # ssim -> 1-ssim (minimize)
             return y
 
-        pb = PipelinedBackend(prepare, dispatch, collect, devices=n_dev)
+        pb = PipelinedBackend(prepare, dispatch, collect,
+                              check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend="jax-shared", chunk_size=chunk_size,
                    fixed_shape=True, cache=cache, overlap=overlap,
                    schema_version=feat.schema.version)
@@ -1295,14 +1333,14 @@ class SurrogateEngine:
             return _Dispatched(on_devices((X, probe.ssim)), probe.check)
 
         def collect(h):
-            h.check()                       # the probe's LUT guards
             Y = np.concatenate([np.asarray(a) for a in h.out], 0)
             mean = ds.denorm_y(Y.mean(0))
             std = Y.std(0) * np.asarray(ds.y_std)
             mean[:, 3] = 1 - mean[:, 3]     # ssim -> 1-ssim (minimize)
             return np.concatenate([mean, std], 1)
 
-        pb = PipelinedBackend(prepare, dispatch, collect, devices=n_dev)
+        pb = PipelinedBackend(prepare, dispatch, collect,
+                              check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend="gnn-ensemble", chunk_size=chunk_size,
                    fixed_shape=True, cache=cache, obj_cols=n_obj,
                    overlap=overlap, schema_version=feat.schema.version)

@@ -5,7 +5,10 @@ also added to an `EngineStats` timer. Recorded on the CPU, the trace must
 show every span where the engine says it runs (``engine.*`` on the calling
 thread inside ``engine.call``, ``featurize.*`` on the prefetch worker's
 line), tie each worker span to its call and chunk, and agree with the
-counters: a counter is the summed duration of its spans.
+counters: a counter is the summed duration of its spans. Where the probe
+reads truth tables (kmeans), ``engine.guards`` reads its guards once a chunk
+inside ``engine.collect``, and the ``featurize.probe`` spans and
+``EngineStats.lut_reads`` count the table entries gathered; sobel reads none.
 """
 import jax
 import numpy as np
@@ -23,7 +26,8 @@ COUNTERS = {"wall_time_s": ("engine.call",),
             "collect_s": ("engine.collect",),
             "featurize_s": ("featurize.chunk",),
             "timing_s": ("featurize.timing",),
-            "probe_s": ("featurize.probe",)}
+            "probe_s": ("featurize.probe",),
+            "guard_s": ("engine.guards",)}
 
 
 def _traced(tmp_path, fn):
@@ -111,15 +115,17 @@ def test_single_chunk_call_opens_one_backend_span_and_waits_for_nothing(
     _check_counters(eng.stats, lines)
 
 
-@pytest.fixture(scope="module")
-def sobel_engine():
+def _gnn_engine(app_name):
+    """A pipelined `from_gnn` engine over `app_name`'s pruned space, every
+    chunk shape compiled and its stats reset, with 48 configurations it
+    has not seen (three chunks of 16)."""
     from repro.accel import apps as apps_lib
     from repro.core import dataset as ds_lib, gnn, models, pruning
 
     pruned, _ = pruning.prune_library()
-    app = apps_lib.APPS["sobel"]
+    app = apps_lib.APPS[app_name]
     entries = {k: pruned[k] for k in {n.kind for n in app.unit_nodes}}
-    ds = ds_lib.build("sobel", n_samples=24, seed=0, lib_entries=entries)
+    ds = ds_lib.build(app_name, n_samples=24, seed=0, lib_entries=entries)
     two_cfg = models.TwoStageConfig(gnn=gnn.GNNConfig(
         arch="gsae", n_layers=2, hidden=16, feature_dim=ds.x.shape[-1]))
     params = models.init(jax.random.PRNGKey(0), two_cfg)
@@ -132,6 +138,16 @@ def sobel_engine():
     eng.clear_cache()
     eng.reset_stats()
     return eng, cfgs[:48]
+
+
+@pytest.fixture(scope="module")
+def sobel_engine():
+    return _gnn_engine("sobel")
+
+
+@pytest.fixture(scope="module")
+def kmeans_engine():
+    return _gnn_engine("kmeans")
 
 
 def test_gnn_engine_names_every_phase_on_its_thread(tmp_path, sobel_engine):
@@ -147,3 +163,60 @@ def test_gnn_engine_names_every_phase_on_its_thread(tmp_path, sobel_engine):
     assert eng.stats.timing_s > 0 and eng.stats.probe_s > 0
     assert eng.stats.timing_s + eng.stats.probe_s <= eng.stats.featurize_s
     _check_counters(eng.stats, lines)
+
+
+def _lut_reads_per_config(app_name):
+    """Truth-table entries one configuration's probe reads, reckoned from
+    the app's graph: each LUT-tabulated unit node (`library.LUT_DOMAINS`)
+    is applied once per pixel of every probe image (one image per scale in
+    `apps.PROBE_SIZES`)."""
+    from repro.accel import apps as apps_lib
+    from repro.accel import library as lib
+
+    app = apps_lib.APPS[app_name]
+    per_pixel = sum(n.kind in lib.LUT_DOMAINS for n in app.unit_nodes)
+    return per_pixel * sum(size * size for size in apps_lib.PROBE_SIZES)
+
+
+def test_kmeans_engine_reads_its_guards_once_a_chunk(tmp_path,
+                                                     kmeans_engine):
+    eng, cfgs = kmeans_engine
+    lines = _traced(tmp_path, lambda: eng(cfgs))
+    calling, workers = _check_lines(lines, n_chunks=3, call=1)
+    guards = _named([calling], "engine.guards")
+    assert sorted(a["chunk"] for *_, a in guards) == [0, 1, 2]
+    collects = _named([calling], "engine.collect")
+    for _, s, e, a in guards:
+        assert a["call"] == 1
+        assert any(cs <= s <= e <= ce and ca["chunk"] == a["chunk"]
+                   for _, cs, ce, ca in collects)
+    assert eng.stats.guard_s > 0
+    assert eng.stats.guard_s <= eng.stats.collect_s
+    _check_counters(eng.stats, lines)
+
+
+def test_kmeans_lut_reads_are_reckoned_from_graph_probe_and_configs(
+        tmp_path, kmeans_engine):
+    eng, cfgs = kmeans_engine
+    per_config = _lut_reads_per_config("kmeans")
+    assert per_config == 8 * (8 * 8 + 16 * 16)     # 6 mul8 + 2 sqrt18
+    eng.clear_cache()
+    eng.reset_stats()
+    lines = _traced(tmp_path, lambda: eng(cfgs))
+    probes = _named(lines, "featurize.probe")
+    assert [a["lut_reads"] for *_, a in probes] == [16 * per_config] * 3
+    assert eng.stats.lut_reads == len(cfgs) * per_config
+    assert eng.stats.as_dict()["lut_reads"] == len(cfgs) * per_config
+
+
+def test_sobel_reads_no_table_and_no_guard(tmp_path, sobel_engine):
+    eng, cfgs = sobel_engine
+    assert _lut_reads_per_config("sobel") == 0
+    eng.clear_cache()
+    eng.reset_stats()
+    lines = _traced(tmp_path, lambda: eng(cfgs))
+    assert not _named(lines, "engine.guards")
+    probes = _named(lines, "featurize.probe")
+    assert len(probes) == 3
+    assert all(a["lut_reads"] == 0 for *_, a in probes)
+    assert eng.stats.lut_reads == 0 and eng.stats.guard_s == 0.0

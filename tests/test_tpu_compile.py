@@ -124,8 +124,8 @@ def test_labeling_model_compiles(one_chip, app_name):
 
     app = apps.APPS[app_name]
     entries = {n.kind: lib.build_library(n.kind) for n in app.unit_nodes}
-    run_chunk, _ = apps._batch_label_fn(app_name,
-                                        apps._entries_items(app, entries))
+    run_chunk = apps._batch_label_fn(app_name,
+                                     apps._entries_items(app, entries)).fn
     # the pipeline's labeling set: 4 RGB (kmeans) or gray 64x64 images
     images = (4, 64, 64, 3) if app_name == "kmeans" else (4, 64, 64)
     exact = jax.eval_shape(
