@@ -442,7 +442,10 @@ def probe_scalar(app: AccelDef, choice: Dict[str, lib.LibEntry]
 #   * multipliers and sqrt (the transcendental-heavy families: mitchell,
 #     drum, pwl, newton) go through stacked LUT truth tables
 #     (`library.stacked_lut`) with the per-config library choice folded
-#     into the table index, read by one XLA gather on every platform;
+#     into the table index, read by one XLA gather on every platform; a
+#     squarer, fed one operand twice, reads its row of the tables'
+#     diagonals (`library.lut_diagonal`) instead, by a compare-select-sum
+#     on a TPU and a gather elsewhere (`lut_square`);
 #   * adders/subtractors, whose widened truth tables would need 2^24-2^32
 #     entries, are evaluated analytically with the family id and cut
 #     parameter as traced per-config scalars (`units.addsub_batched`);
@@ -464,17 +467,49 @@ def lut_gather(table: jax.Array, a: jax.Array, b: jax.Array,
         return jnp.take(table, (a << wb) | b, axis=0)
 
 
+def lut_square_select(rows: jax.Array, e: jax.Array,
+                      a: jax.Array) -> jax.Array:
+    """A squarer's read, ``m(a, a)``, as an exact int32 compare-select-sum
+    of ``a`` against entry ``e``'s row of its tables' diagonals
+    (`library.lut_diagonal`): one nonzero term, 0 where ``a`` leaves the
+    row, which the caller's guard reports."""
+    hit = a[..., None] == jnp.arange(rows.shape[-1], dtype=a.dtype)
+    return jnp.sum(jnp.where(hit, rows[e], 0), axis=-1)
+
+
+def lut_square_take(rows: jax.Array, e: jax.Array,
+                    a: jax.Array) -> jax.Array:
+    """The same read as `lut_square_select`, by a gather of the flattened
+    diagonals at ``(e << w) | a`` (`lut_gather`)."""
+    return lut_gather(rows.reshape(-1), e, a, rows.shape[-1].bit_length() - 1)
+
+
+def lut_square(rows: jax.Array, e: jax.Array, a: jax.Array) -> jax.Array:
+    """A squarer's read, ``m(a, a)``, from entry ``e``'s row of its
+    tables' diagonals, in the form that suits the platform; both return
+    the same integers on the table's domain. On a TPU the compare-select-
+    sum (`lut_square_select`): a v5e gather costs about as much per index
+    from a 47 KB table as from a 24 MB one, and at the probe's shapes the
+    compare takes about a ninth of it (``benchmarks/lut_square_forms.py``).
+    Elsewhere the gather (`lut_square_take`), which a CPU runs over a
+    hundred times faster than the 512-lane compare."""
+    return jax.lax.platform_dependent(rows, e, a, tpu=lut_square_select,
+                                      default=lut_square_take)
+
+
 class Labeler(NamedTuple):
     """The compiled labeler of one app over one library (`batch_labeler`).
 
     ``fn(C, images, exact_out) -> ((B,) ssim, guards)``; ``guard_meta``
     maps each guard tag to its unit kind and LUT domain; both are filled
     when the model is traced. ``lut_reads(images)`` is the number of
-    table entries one configuration's model gathers on ``images``,
-    counted while tracing it."""
+    table entries one configuration's model reads on ``images``, and
+    ``lut_square_reads(images)`` those of them a squarer reads from its
+    table's diagonal, both counted while tracing it."""
     fn: Callable
     guard_meta: Dict[str, Tuple[str, int, int]]
     lut_reads: Callable[[jax.Array], int]
+    lut_square_reads: Callable[[jax.Array], int]
 
 
 def _entries_items(app: AccelDef, entries: Dict[str, Sequence]
@@ -494,7 +529,21 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
     app = APPS[app_name]
     entries = dict(entries_items)
     guard_meta: Dict[str, Tuple[str, int, int]] = {}
-    reads: Dict[Tuple[int, ...], int] = {}
+    reads: Dict[Tuple[int, ...], Tuple[int, int]] = {}
+
+    tables: Dict[Tuple[str, Callable], jax.Array] = {}
+
+    def device_table(kind_name, host_fn):
+        # ``host_fn`` (`library.stacked_lut` or `lut_diagonal`) of one LUT
+        # kind on the device, shared by the app's units of that kind and
+        # uploaded by the first trace that reads it (kmeans's squarers
+        # never read their full 24 MB table)
+        if (kind_name, host_fn) not in tables:
+            ea, eb = lib.lut_domain(app_name, kind_name)
+            with jax.ensure_compile_time_eval():
+                tables[kind_name, host_fn] = jnp.asarray(
+                    host_fn(tuple(entries[kind_name]), ea, eb))
+        return tables[kind_name, host_fn]
 
     node_data = []
     for node in app.unit_nodes:
@@ -502,14 +551,13 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
         kind = units_lib.KINDS[node.kind]
         if node.kind in lib.LUT_DOMAINS:
             ea, eb = lib.lut_domain(app_name, node.kind)
-            table = jnp.asarray(lib.stacked_lut(ent, ea, eb))
-            node_data.append(("lut", node, kind, ea, eb, table))
+            node_data.append(("lut", node, kind, ea, eb))
         else:
             fam, k, seg = lib.addsub_dispatch(ent)
             node_data.append(("analytic", node, kind, jnp.asarray(fam),
                               jnp.asarray(k), jnp.asarray(seg)))
 
-    def _lut_impl(node, kind, ea, eb, table, e, guards, counts, n_read):
+    def _lut_impl(node, kind, ea, eb, e, guards, counts, n_read):
         unary = kind.op == "sqrt"
 
         def excess(x, bits):
@@ -524,6 +572,15 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
             guard_meta[tag] = (kind.name, ea, eb)
             n_read[0] += a.size          # one table entry per operand
             zero = jnp.zeros((), jnp.int32)
+            if b is a:
+                # one operand fed twice (kmeans's m(d, d)): only the
+                # table's diagonal can be read
+                ex = excess(a, min(ea, eb))
+                guards[tag] = (ex, ex)
+                n_read[1] += a.size
+                return lut_square(device_table(node.kind, lib.lut_diagonal),
+                                  e, a)
+            table = device_table(node.kind, lib.stacked_lut)
             if unary:
                 guards[tag] = (excess(a, ea), zero)
                 af = ((e << ea) | a).reshape(-1)
@@ -558,23 +615,22 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
         return impl
 
     def model_chunk(C, images):
-        n_read = [0]
+        n_read = [0, 0]             # table entries read, of them squares
 
         def model_one(cfg):
             impls, guards, counts = {}, {}, {}
             for j, nd in enumerate(node_data):
                 if nd[0] == "lut":
-                    _, node, kind, ea, eb, table = nd
-                    impls[node.id] = _lut_impl(node, kind, ea, eb, table,
-                                               cfg[j], guards, counts,
-                                               n_read)
+                    _, node, kind, ea, eb = nd
+                    impls[node.id] = _lut_impl(node, kind, ea, eb, cfg[j],
+                                               guards, counts, n_read)
                 else:
                     _, node, kind, fam, k, seg = nd
                     impls[node.id] = _analytic_impl(kind, fam, k, seg,
                                                     cfg[j])
             return app.run(impls, images), guards
         out = jax.vmap(model_one)(C)
-        reads[tuple(images.shape)] = n_read[0]
+        reads[tuple(images.shape)] = tuple(n_read)
         return out
 
     def ssim_chunk(out, exact_out):
@@ -588,9 +644,9 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
         out, guards = _jit_model(C, images)
         return _jit_ssim(out, exact_out), guards
 
-    def lut_reads(images) -> int:
+    def lut_counts(images) -> Tuple[int, int]:
         if not any(nd[0] == "lut" for nd in node_data):
-            return 0
+            return 0, 0
         if tuple(images.shape) not in reads:
             # trace (never compile) one configuration's model
             jax.eval_shape(model_chunk, jax.ShapeDtypeStruct(
@@ -599,7 +655,9 @@ def _batch_label_fn(app_name: str, entries_items) -> Labeler:
 
     _jit_model = jax.jit(model_chunk)
     _jit_ssim = jax.jit(ssim_chunk)
-    return Labeler(run_chunk, guard_meta, lut_reads)
+    return Labeler(run_chunk, guard_meta,
+                   lambda images: lut_counts(images)[0],
+                   lambda images: lut_counts(images)[1])
 
 
 def _check_lut_guards(app: AccelDef, guard_meta, guards) -> None:

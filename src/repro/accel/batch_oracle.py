@@ -293,10 +293,17 @@ class DeviceProber:
 
     @functools.cached_property
     def lut_reads(self) -> int:
-        """Truth-table entries the probe gathers per configuration, over
+        """Truth-table entries the probe reads per configuration, over
         every scale (`apps.Labeler.lut_reads`; 0 for an app without LUT
         units)."""
         return sum(self._labeler.lut_reads(images)
+                   for images, _ in self._image_sets)
+
+    @functools.cached_property
+    def lut_square_reads(self) -> int:
+        """Of `lut_reads`, the entries squarers read from their tables'
+        diagonals (`apps.Labeler.lut_square_reads`)."""
+        return sum(self._labeler.lut_square_reads(images)
                    for images, _ in self._image_sets)
 
     def __call__(self, configs) -> DeviceProbe:

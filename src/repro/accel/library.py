@@ -338,6 +338,17 @@ def stacked_lut(entries: Tuple[LibEntry, ...], ea: int, eb: int
 
 
 @functools.lru_cache(maxsize=None)
+def lut_diagonal(entries: Tuple[LibEntry, ...], ea: int, eb: int
+                 ) -> np.ndarray:
+    """The diagonals of `stacked_lut`, (len(entries), 2^w) int32 with
+    ``w = min(ea, eb)``: row ``i`` holds entry ``i``'s values at (a, a),
+    all a squarer (a unit fed the same operand twice) can read."""
+    a = np.arange(1 << min(ea, eb))
+    return np.ascontiguousarray(stacked_lut(entries, ea, eb).reshape(
+        len(entries), 1 << ea, 1 << eb)[:, a, a])
+
+
+@functools.lru_cache(maxsize=None)
 def addsub_dispatch(entries: Tuple[LibEntry, ...]
                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """(family ids, cut params, seg carry-kill masks) per entry, for

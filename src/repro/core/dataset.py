@@ -514,8 +514,10 @@ class ConfigFeaturizer:
         the host sweeps; its ``featurize.probe`` span (``probe_s``) covers
         building the config block and dispatching, and its ``lut_reads``
         argument, added to ``stats.lut_reads``, counts the truth-table
-        entries the probe gathers (`batch_oracle.DeviceProber.lut_reads`
-        per configuration).
+        entries the probe reads (`batch_oracle.DeviceProber.lut_reads`
+        per configuration); its ``lut_square_reads`` argument, added to
+        ``stats.lut_square_reads``, those of them squarers read from their
+        tables' diagonals.
         """
         from repro.accel import batch_oracle
         if self._norm is None:
@@ -531,11 +533,12 @@ class ConfigFeaturizer:
                 self._prober = batch_oracle.DeviceProber(self._app,
                                                          self._entries)
             reads = self._prober.lut_reads * C.shape[0]
+            squares = self._prober.lut_square_reads * C.shape[0]
             with _span(stats, "featurize.probe", "probe_s",
-                       lut_reads=reads):
+                       lut_reads=reads, lut_square_reads=squares):
                 probe = self._prober(C)
             if stats is not None:
-                stats.update(lut_reads=reads)
+                stats.update(lut_reads=reads, lut_square_reads=squares)
         if self.dynamic:
             with _span(stats, "featurize.timing", "timing_s"):
                 rep = batch_oracle.timing_batch(self._app, self._entries, C)

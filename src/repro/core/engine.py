@@ -164,6 +164,9 @@ class EngineStats:
                       pixels x configurations, counted when the labeler
                       is traced, never on the device; 0 without LUT
                       units).
+        lut_square_reads: of ``lut_reads``, the entries squarers read
+                      from their tables' diagonals (0 where no LUT unit
+                      is fed one operand twice).
 
     ``wall_time_s`` and the timers from ``featurize_s`` on are summed
     durations of the engine's `span`s (``engine.*`` on the calling
@@ -194,6 +197,7 @@ class EngineStats:
     probe_on_device: int = 0
     guard_s: float = 0.0
     lut_reads: int = 0
+    lut_square_reads: int = 0
 
     def __post_init__(self):
         self._lock = threading.Lock()
@@ -278,7 +282,8 @@ class EngineStats:
                     "memo_s": round(self.memo_s, 4),
                     "probe_on_device": self.probe_on_device,
                     "guard_s": round(self.guard_s, 4),
-                    "lut_reads": self.lut_reads}
+                    "lut_reads": self.lut_reads,
+                    "lut_square_reads": self.lut_square_reads}
             overlap = self.overlap_fraction
         snap["cache_hit_rate"] = round(
             snap["cache_hits"] / snap["configs"], 4) if snap["configs"] \

@@ -69,8 +69,19 @@ def test_synthesize_batch_exact_config_and_determinism():
     assert r1["area"][0] == r1["area"][1] == r1["area"][2]
 
 
+# (table entries, of them squares) the functional probe reads per
+# configuration over its 8x8 and 16x16 images: kmeans's 6 mul8 squarers
+# and 2 sqrt18; gaussian's 9 and fir15's 8 mul8x4 with a constant operand,
+# dct8's 4 once in each pass; sobel has no LUT unit
+PROBE_READS = {"sobel": (0, 0), "gaussian": (9 * 320, 0),
+               "kmeans": (8 * 320, 6 * 320), "dct8": (8 * 320, 0),
+               "fir15": (8 * 320, 0)}
+
+
 @pytest.mark.parametrize("name", ALL_APPS)
 def test_accuracy_ssim_batch_parity(name, imgset):
+    """Batched labels match the scalar path; only kmeans's squarers read
+    a diagonal table, as the probe's counts show."""
     g, rgb = imgset
     app = apps.APPS[name]
     entries = _entries(app)
@@ -82,6 +93,8 @@ def test_accuracy_ssim_batch_parity(name, imgset):
                   for node, c in zip(app.unit_nodes, row)}
         want = apps.accuracy_ssim(app, choice, inp)
         assert got[i] == pytest.approx(want, abs=2e-5), (name, i)
+    prober = batch_oracle.DeviceProber(app, entries)
+    assert (prober.lut_reads, prober.lut_square_reads) == PROBE_READS[name]
 
 
 def test_accuracy_ssim_batch_ragged_chunk(imgset):
@@ -151,6 +164,96 @@ def test_lut_domain_guard_raises(imgset):
             apps.accuracy_ssim_batch(app, entries, C, g, chunk=4)
     finally:
         lib.APP_LUT_DOMAINS[key] = old
+        apps._batch_label_fn.cache_clear()
+
+
+SQUARE_FORMS = {"select": apps.lut_square_select, "take": apps.lut_square_take}
+
+
+@pytest.mark.parametrize("form", sorted(SQUARE_FORMS))
+def test_lut_square_reads_the_diagonal(form):
+    """For every mul8 entry (kmeans's pruned ones among them) and every
+    operand in the (9, 9) domain, the squarer's diagonal read equals the
+    full table's value at (a, a), in both of `apps.lut_square`'s forms."""
+    import jax
+
+    entries = tuple(lib.build_library("mul8"))
+    ea, eb = lib.LUT_DOMAINS["mul8"]
+    rows = jnp.asarray(lib.lut_diagonal(entries, ea, eb))
+    assert rows.shape == (len(entries), 1 << ea)
+    table = jnp.asarray(lib.stacked_lut(entries, ea, eb))
+    idx = jnp.arange(len(entries), dtype=jnp.int32)
+    a = jnp.broadcast_to(jnp.arange(1 << ea, dtype=jnp.int32),
+                         (len(entries), 1 << ea))
+    square = SQUARE_FORMS[form]
+    got = jax.jit(jax.vmap(lambda e, x: square(rows, e, x)))(idx, a)
+    want = apps.lut_gather(table, (idx[:, None] << ea) | a, a, eb)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _full_table_twin(app):
+    """``app`` under another name, its squarers fed a copy of their
+    operand, so they read the full table as any two-operand unit does."""
+    import dataclasses
+
+    def run(impls, images):
+        twin = {k: (lambda f: lambda a, b=None: f(a, a + 0 if b is a else b))
+                (f) for k, f in impls.items()}
+        return app.run(twin, images)
+    return dataclasses.replace(app, name=app.name + "_full_table", run=run)
+
+
+@pytest.mark.parametrize("form", ["platform", "select"])
+def test_kmeans_squarers_match_the_full_table(imgset, monkeypatch, form):
+    """A 512-config kmeans block labels and probes bit-identically through
+    the squarers' diagonal and through full-table gathers: in the form the
+    platform picks (a gather here) and in the TPU's compare-select-sum."""
+    _, rgb = imgset
+    app = apps.APPS["kmeans"]
+    twin = _full_table_twin(app)
+    monkeypatch.setitem(apps.APPS, twin.name, twin)
+    if form == "select":
+        # labelers built apart from the cache, squaring in the TPU's form
+        monkeypatch.setattr(apps, "lut_square", apps.lut_square_select)
+        monkeypatch.setattr(apps, "_batch_label_fn",
+                            apps._batch_label_fn.__wrapped__)
+    entries = _entries(app)
+    C = _rand_configs(app, entries, 512, seed=16)
+    diag, full = (apps.batch_labeler(a, entries) for a in (app, twin))
+    assert diag.lut_square_reads(rgb) == 6 * rgb.size // 3
+    assert full.lut_square_reads(rgb) == 0
+    assert diag.lut_reads(rgb) == full.lut_reads(rgb)
+    probe_sets = [apps.probe_inputs("kmeans", s) for s in apps.PROBE_SIZES]
+    exact = app.run(apps.make_impls(app, apps.exact_choice(app)), rgb)
+    for image_sets in (probe_sets, [(rgb, exact)]):
+        outs = []
+        for a, labeler in ((app, diag), (twin, full)):
+            scores, check = apps.ssim_batch_on_device(a, labeler, C,
+                                                      image_sets, chunk=512)
+            check()
+            outs.append([np.asarray(s) for s in scores])
+        for got, want in zip(*outs):
+            np.testing.assert_array_equal(got, want)
+
+
+def test_squarer_operand_outside_the_domain_raises(monkeypatch):
+    """A squarer fed 512, one past the (9, 9) domain, raises with the
+    guard's message, as a full-table read did."""
+    from repro.accel.apps import AccelDef, Node
+
+    app = AccelDef("square_probe", (Node("m", "mul8"),), (),
+                   lambda impls, x: impls["m"](x, x))
+    entries = {"mul8": lib.build_library("mul8")[:3]}
+    x = jnp.full((1, 8, 8), 511, jnp.int32)
+    monkeypatch.setitem(apps.APPS, app.name, app)
+    try:
+        C = np.arange(3)[:, None]
+        apps.accuracy_ssim_batch(app, entries, C, x, chunk=4)   # in domain
+        with pytest.raises(apps.LutDomainError,
+                           match=r"unit m#0 \(mul8\) left its LUT domain "
+                                 r"\(2\^9, 2\^9\) by up to a:1 b:1"):
+            apps.accuracy_ssim_batch(app, entries, C, x + 1, chunk=4)
+    finally:
         apps._batch_label_fn.cache_clear()
 
 

@@ -8,7 +8,8 @@ line), tie each worker span to its call and chunk, and agree with the
 counters: a counter is the summed duration of its spans. Where the probe
 reads truth tables (kmeans), ``engine.guards`` reads its guards once a chunk
 inside ``engine.collect``, and the ``featurize.probe`` spans and
-``EngineStats.lut_reads`` count the table entries gathered; sobel reads none.
+``EngineStats.lut_reads`` count the table entries read, ``lut_square_reads``
+those its squarers read from their tables' diagonals; sobel reads none.
 """
 import jax
 import numpy as np
@@ -207,6 +208,11 @@ def test_kmeans_lut_reads_are_reckoned_from_graph_probe_and_configs(
     assert [a["lut_reads"] for *_, a in probes] == [16 * per_config] * 3
     assert eng.stats.lut_reads == len(cfgs) * per_config
     assert eng.stats.as_dict()["lut_reads"] == len(cfgs) * per_config
+    # the 6 mul8 squarers read their tables' diagonals
+    squares = 6 * (8 * 8 + 16 * 16)
+    assert [a["lut_square_reads"] for *_, a in probes] == [16 * squares] * 3
+    assert eng.stats.lut_square_reads == len(cfgs) * squares
+    assert eng.stats.as_dict()["lut_square_reads"] == len(cfgs) * squares
 
 
 def test_sobel_reads_no_table_and_no_guard(tmp_path, sobel_engine):
@@ -218,5 +224,7 @@ def test_sobel_reads_no_table_and_no_guard(tmp_path, sobel_engine):
     assert not _named(lines, "engine.guards")
     probes = _named(lines, "featurize.probe")
     assert len(probes) == 3
-    assert all(a["lut_reads"] == 0 for *_, a in probes)
-    assert eng.stats.lut_reads == 0 and eng.stats.guard_s == 0.0
+    assert all(a["lut_reads"] == a["lut_square_reads"] == 0
+               for *_, a in probes)
+    assert eng.stats.lut_reads == eng.stats.lut_square_reads == 0
+    assert eng.stats.guard_s == 0.0

@@ -136,6 +136,9 @@ def test_labeling_model_compiles(one_chip, app_name):
         _sds(images, jnp.int32, one_chip),
         _sds(exact.shape, exact.dtype, one_chip)).compile()
     assert "gather" in compiled.as_text()
+    # kmeans's squarers compare each pixel with 512 diagonal values: fused
+    # into their sums, not held as a (256, 4, 64, 64, 512) int32 block
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
 @pytest.mark.parametrize("n_islands,members", [(4, 32), (4, 128)])
