@@ -1,7 +1,7 @@
 """One benchmark per paper table/figure (Tables II-VIII, Figs 4-6).
 
 Each function prints `name,value,derived` CSV rows. Scales are CPU-reduced
-by default; --paper-faithful uses the original sample counts (slow).
+(`SCALE`); `benchmarks/run.py --quick` reduces them further.
 """
 from __future__ import annotations
 

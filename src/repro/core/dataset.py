@@ -4,17 +4,18 @@ Random sampling over the (pruned) design space with symmetric-structure
 deduplication; labels from the simulated synthesis oracle (PPA + critical
 path) and the vectorized functional model (SSIM on the image set).
 
-Labeling runs through the batched ground-truth engine by default
+`build` labels through the batched ground-truth engine
 (`repro.accel.batch_oracle.synthesize_batch` + the config-batched LUT
 functional model `apps.accuracy_ssim_batch`): the whole sample block is
 labeled as (B, ...) array programs instead of a per-config Python loop.
-``build(..., label_backend="loop")`` keeps the scalar reference path —
-tests/test_batch_oracle.py asserts the labels are equivalent (bit-identical
-critical bits, float-tolerance PPA/SSIM). Feature tensors are assembled by
-`ConfigFeaturizer`, which caches every config-independent column.
+`build_loop` is the scalar reference — tests/test_batch_oracle.py asserts
+the labels are equivalent (bit-identical critical bits, float-tolerance
+PPA/SSIM). Feature tensors are assembled by `ConfigFeaturizer`, which
+caches every config-independent column.
 
 Paper scale: 55k/105k/105k samples, 90/10 split. CPU-scaled defaults are
-smaller; pass --paper-faithful in benchmarks to use the original sizes.
+smaller; `pipeline.PipelineConfig.paper_faithful(app)` uses the original
+sizes.
 """
 from __future__ import annotations
 
@@ -315,16 +316,11 @@ class ConfigFeaturizer:
     tests/test_feature_schema.py): both paths cast the float64 timing
     sweep to float32 once and then apply the elementwise-identical
     standardization.
-
-    ``dynamic=False`` skips the timing sweep (the dynamic columns keep
-    their constant base values) — an ablation/measurement knob used by
-    benchmarks/engine_bench.py's overhead gate, not a serving mode.
     """
 
     def __init__(self, g: graph_lib.SimpleGraph, app: apps_lib.AccelDef,
                  entries: Dict[str, Sequence], n_pad: int,
-                 schema: Optional[graph_lib.FeatureSchema] = None,
-                 dynamic: bool = True):
+                 schema: Optional[graph_lib.FeatureSchema] = None):
         self.schema = schema or graph_lib.ACTIVE_SCHEMA
         self.n_pad = n_pad
         self.n_nodes = len(g.node_ids)
@@ -332,7 +328,7 @@ class ConfigFeaturizer:
         self._graph = g
         self._app = app
         self._entries = entries
-        self.dynamic = dynamic and bool(self.schema.dynamic_fields)
+        self.dynamic = bool(self.schema.dynamic_fields)
         self._members: Optional[List[np.ndarray]] = None
         # `normalized_on_device` runs on the engine's featurize worker
         # thread (the overlap pipeline) while other engines sharing this
@@ -577,8 +573,75 @@ def build(app_name: str, n_samples: int = 2000, seed: int = 0,
           n_images: int = 4, img_size: int = 64,
           lib_entries: Optional[Dict[str, Sequence]] = None,
           simplify_graph: bool = True, n_pad: int = 32,
-          label_backend: str = "batched",
           label_chunk: int = 256) -> AccelDataset:
+    """Sample ``n_samples`` configurations and label them with the batched
+    oracle (`build_loop` is the scalar reference)."""
+    return _build(app_name, n_samples, seed, n_images, img_size,
+                  lib_entries, simplify_graph, n_pad,
+                  lambda *a: _label_batched(*a, chunk=label_chunk))
+
+
+def build_loop(app_name: str, n_samples: int = 2000, seed: int = 0,
+               n_images: int = 4, img_size: int = 64,
+               lib_entries: Optional[Dict[str, Sequence]] = None,
+               simplify_graph: bool = True,
+               n_pad: int = 32) -> AccelDataset:
+    """Reference for `build`: the same dataset, labeled by one scalar
+    oracle and functional-model call per configuration."""
+    return _build(app_name, n_samples, seed, n_images, img_size,
+                  lib_entries, simplify_graph, n_pad, _label_loop)
+
+
+def _label_batched(app, g, entries, configs, inp, exact_out, n_pad, *,
+                   chunk: int):
+    """(A, X, M, y_raw) by the batched oracle and the config-batched LUT
+    functional model."""
+    from repro.accel import batch_oracle
+    C = np.asarray(configs, np.int64)
+    rep = batch_oracle.synthesize_batch(app, entries, C)
+    acc = apps_lib.accuracy_ssim_batch(app, entries, C, inp, exact_out,
+                                       chunk=chunk)
+    y_raw = np.stack([rep["area"], rep["power"], rep["latency"], acc],
+                     axis=1).astype(np.float32)
+    # map app-node critical bits onto the (possibly merged) graph nodes
+    pos = {nid: a for a, nid in enumerate(rep["node_ids"])}
+    memb = np.zeros((len(g.node_ids), len(rep["node_ids"])), np.float32)
+    for i, members in enumerate(g.merged_from):
+        for m in members:
+            memb[i, pos[m]] = 1.0
+    crit_graph = (rep["crit"].astype(np.float32)
+                  @ memb.T > 0).astype(np.float32)
+    feat = ConfigFeaturizer(g, app, entries, n_pad)
+    X = feat.raw(C, crit=crit_graph)
+    A = np.broadcast_to(feat.adj, (len(configs),) + feat.adj.shape).copy()
+    M = np.broadcast_to(feat.mask, (len(configs),) + feat.mask.shape).copy()
+    return A, X, M, y_raw
+
+
+def _label_loop(app, g, entries, configs, inp, exact_out, n_pad):
+    """(A, X, M, y_raw) by one scalar oracle + functional-model call per
+    config."""
+    schema = graph_lib.ACTIVE_SCHEMA
+    adjs, feats, ys = [], [], []
+    for cfg_idx in configs:
+        choice = {node.id: entries[node.kind][i]
+                  for node, i in zip(app.unit_nodes, cfg_idx)}
+        rep = synth.synthesize(app, choice)
+        acc = apps_lib.accuracy_ssim(app, choice, inp, exact_out)
+        timing = (synth.static_timing(app, choice)["nodes"]
+                  if schema.dynamic_fields else None)
+        xf = graph_lib.node_features(g, app, choice,
+                                     crit_nodes=rep["critical_nodes"],
+                                     timing=timing, schema=schema)
+        adjs.append(g.adj)
+        feats.append(xf)
+        ys.append([rep["area"], rep["power"], rep["latency"], acc])
+    A, X, M = graph_lib.pad_batch(adjs, feats, n_pad)
+    return A, X, M, np.asarray(ys, np.float32)
+
+
+def _build(app_name, n_samples, seed, n_images, img_size, lib_entries,
+           simplify_graph, n_pad, label) -> AccelDataset:
     app = apps_lib.APPS[app_name]
     g = graph_lib.build_graph(app, simplify=simplify_graph)
     entries = lib_entries or {k: lib.build_library(k) for k in
@@ -593,51 +656,7 @@ def build(app_name: str, n_samples: int = 2000, seed: int = 0,
                         inp)
 
     configs = sample_configs(app, n_samples, seed, lib_entries=entries)
-    if label_backend == "batched":
-        from repro.accel import batch_oracle
-        C = np.asarray(configs, np.int64)
-        rep = batch_oracle.synthesize_batch(app, entries, C)
-        acc = apps_lib.accuracy_ssim_batch(app, entries, C, inp, exact_out,
-                                           chunk=label_chunk)
-        y_raw = np.stack([rep["area"], rep["power"], rep["latency"], acc],
-                         axis=1).astype(np.float32)
-        # map app-node critical bits onto the (possibly merged) graph nodes
-        pos = {nid: a for a, nid in enumerate(rep["node_ids"])}
-        memb = np.zeros((len(g.node_ids), len(rep["node_ids"])), np.float32)
-        for i, members in enumerate(g.merged_from):
-            for m in members:
-                memb[i, pos[m]] = 1.0
-        crit_graph = (rep["crit"].astype(np.float32)
-                      @ memb.T > 0).astype(np.float32)
-        feat = ConfigFeaturizer(g, app, entries, n_pad)
-        X = feat.raw(C, crit=crit_graph)
-        A = np.broadcast_to(feat.adj,
-                            (len(configs),) + feat.adj.shape).copy()
-        M = np.broadcast_to(feat.mask,
-                            (len(configs),) + feat.mask.shape).copy()
-    elif label_backend == "loop":
-        # scalar reference path: one oracle + functional-model call per
-        # config (kept for parity testing and as the fallback)
-        schema = graph_lib.ACTIVE_SCHEMA
-        adjs, feats, ys = [], [], []
-        for cfg_idx in configs:
-            choice = {node.id: entries[node.kind][i]
-                      for node, i in zip(app.unit_nodes, cfg_idx)}
-            rep = synth.synthesize(app, choice)
-            acc = apps_lib.accuracy_ssim(app, choice, inp, exact_out)
-            timing = (synth.static_timing(app, choice)["nodes"]
-                      if schema.dynamic_fields else None)
-            xf = graph_lib.node_features(g, app, choice,
-                                         crit_nodes=rep["critical_nodes"],
-                                         timing=timing, schema=schema)
-            adjs.append(g.adj)
-            feats.append(xf)
-            ys.append([rep["area"], rep["power"], rep["latency"], acc])
-        A, X, M = graph_lib.pad_batch(adjs, feats, n_pad)
-        y_raw = np.asarray(ys, np.float32)
-    else:
-        raise ValueError(f"label_backend must be 'batched' or 'loop', "
-                         f"got {label_backend!r}")
+    A, X, M, y_raw = label(app, g, entries, configs, inp, exact_out, n_pad)
 
     schema = graph_lib.ACTIVE_SCHEMA
     crit = X[..., schema.crit_index].copy()

@@ -326,8 +326,7 @@ def pareto_mask_blockwise(F: np.ndarray, block: int = 8192) -> np.ndarray:
     transitive), and every global front member survives its chunk cull,
     so the cross-chunk pass over chunk-front survivors reproduces
     `pareto_mask(F)` bit-for-bit (property-tested in
-    tests/test_pareto_props.py). Million-row merged island archives cull
-    in well under a second (benchmarks/dse_bench.py, BENCH_dse.json).
+    tests/test_pareto_props.py).
     """
     F = np.asarray(F)
     n = len(F)
@@ -679,10 +678,10 @@ def nsga_steps(sizes: Sequence[int], evaluate: EvalFn, budget: int,
 
     # incremental archive snapshots: converting the WHOLE tuple archive
     # per checkpoint is O(evaluated) and dominates checkpoint cost at
-    # checkpoint_every=1 (gated <= 5% overhead in benchmarks/dse_bench);
-    # instead only the rows added since the last checkpoint are converted
-    # and appended. The cached arrays are never mutated in place, so
-    # handing them to the sink without a copy is safe.
+    # checkpoint_every=1; instead only the rows added since the last
+    # checkpoint are converted and appended. The cached arrays are never
+    # mutated in place, so handing them to the sink without a copy is
+    # safe.
     ck_arch = {"nX": 0, "X": None, "nF": 0, "F": None}
 
     def _arch_snapshot():
@@ -847,16 +846,10 @@ def _run_islands(*args, **kwargs) -> DSEResult:
     return run_islands(*args, **kwargs)
 
 
-def _run_islands_ref(*args, **kwargs) -> DSEResult:
-    # the scalar parity oracle, selectable from pipelines/benchmarks
-    from repro.core.islands import run_islands_ref
-    return run_islands_ref(*args, **kwargs)
-
-
 SAMPLERS = {"random": run_random, "tpe": run_tpe,
             "nsga2": lambda *a, **k: run_nsga(*a, variant="nsga2", **k),
             "nsga3": lambda *a, **k: run_nsga(*a, variant="nsga3", **k),
-            "islands": _run_islands, "islands_ref": _run_islands_ref}
+            "islands": _run_islands}
 
 
 def iter_sampler(sampler: str, sizes: Sequence[int], evaluate: EvalFn,
@@ -870,7 +863,7 @@ def iter_sampler(sampler: str, sizes: Sequence[int], evaluate: EvalFn,
 
     ``nsga2``/``nsga3`` step truly per generation (`nsga_steps`);
     ``islands`` steps per epoch boundary (`islands_steps`). The
-    sequential state machines (``tpe``, ``random``, ``islands_ref``) have
+    sequential state machines (``tpe``, ``random``) have
     no incremental form — they run to completion on the first advance and
     replay their history, so streaming is post-hoc but the protocol (and
     bit-identity with ``SAMPLERS[name]``) is preserved.

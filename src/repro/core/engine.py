@@ -52,8 +52,8 @@ feature columns are cached constants and the node-feature tensor is
 assembled by table lookup (same cache as
 `repro.core.dataset.features_for_configs`).
 
-See docs/paper_map.md for how this maps onto the paper, and
-benchmarks/engine_bench.py for the batched-vs-naive throughput numbers.
+See docs/paper_map.md for how this maps onto the paper; the chip
+benchmark (`bench/`, `BENCHMARK.json`) measures its throughput.
 """
 from __future__ import annotations
 
@@ -628,22 +628,19 @@ class SurrogateEngine:
     Args:
         batch_fn:    ``configs -> (len(configs), n_obj)`` backend, or a
                      `PipelinedBackend` whose prepare/dispatch/collect
-                     phases the engine overlaps across chunks.
+                     phases the engine overlaps whenever a call spans
+                     >= 2 chunks: chunk k+1 featurizes on a worker thread
+                     while chunk k computes on device, and transfers are
+                     deferred until every chunk is in flight.
+                     Bit-identical to the serial path, which a plain
+                     callable and single-chunk calls take
+                     (tests/test_engine_sharded.py).
         backend:     label for stats/reporting ("jax", "pallas", ...).
         chunk_size:  maximum configs per backend call. ``None`` disables
                      chunking entirely — the whole miss list goes to the
                      backend in one call (used by `queued_view`, whose
                      coalescing decisions belong to the drain side; only
                      valid with ``fixed_shape=False``).
-        overlap:     pipeline chunk evaluation when the backend is a
-                     `PipelinedBackend` and a call spans >= 2 chunks:
-                     chunk k+1 featurizes on a worker thread while chunk
-                     k computes on device, and transfers are deferred
-                     until every chunk is in flight. Bit-identical to the
-                     serial path (the identical phase functions run in
-                     the identical per-chunk order). ``None`` = auto (on
-                     exactly when the backend is pipelined); ``False``
-                     forces the serial path.
         fixed_shape: pad ragged final chunks up to a power-of-two bucket so
                      jit-compiled backends see a bounded set of shapes.
                      Leave False for shape-insensitive backends (oracle,
@@ -682,7 +679,6 @@ class SurrogateEngine:
     def __init__(self, batch_fn: BatchFn, *, backend: str = "generic",
                  chunk_size: Optional[int] = 512,
                  fixed_shape: bool = False,
-                 overlap: Optional[bool] = None,
                  cache: bool = True, max_cache: int = 1_000_000,
                  obj_cols: Optional[int] = None, retry=None,
                  nan_guard: bool = True, nan_retries: int = 2,
@@ -696,8 +692,6 @@ class SurrogateEngine:
         self._batch_fn = batch_fn
         self._pipeline = batch_fn if isinstance(batch_fn, PipelinedBackend) \
             else None
-        self.overlap = (self._pipeline is not None) if overlap is None \
-            else bool(overlap)
         self.devices = self._pipeline.devices if self._pipeline else 1
         self._warned_padding = False
         self.backend = backend
@@ -1025,7 +1019,7 @@ class SurrogateEngine:
                       call: int) -> np.ndarray:
         plan = self._plan_chunks(configs)
         self._warn_padding(plan, len(configs))
-        if self.overlap and self._pipeline is not None and len(plan) >= 2:
+        if self._pipeline is not None and len(plan) >= 2:
             return self._eval_pipelined(plan, configs, call)
         rows = []
         for idx, (i, take, chunk) in enumerate(plan):
@@ -1138,16 +1132,14 @@ class SurrogateEngine:
     def from_gnn(cls, two_cfg, params, ds, app,
                  entries: Dict[str, Sequence], *, chunk_size: int = 512,
                  use_kernel: str = "auto", cache: bool = True,
-                 devices: int = 1, overlap: Optional[bool] = None
-                 ) -> "SurrogateEngine":
+                 devices: int = 1) -> "SurrogateEngine":
         """GNN-surrogate engine (the ApproxPilot fast path).
 
         Featurizes by table lookup, runs the two-stage model under jit with
         bucketed batch shapes, denormalizes and flips ssim to the
         minimized ``1 - ssim`` objective. The backend is a
         `PipelinedBackend`, so multi-chunk calls overlap host
-        featurization with device compute by default (``overlap``, see
-        `SurrogateEngine` — disableable for measurement).
+        featurization with device compute (see `SurrogateEngine`).
 
         ``devices``: spread chunks over up to this many local devices
         (``0`` = all of them), one whole chunk per device in turn
@@ -1217,16 +1209,13 @@ class SurrogateEngine:
         pb = PipelinedBackend(prepare, dispatch, collect,
                               check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend=backend, chunk_size=chunk_size,
-                   fixed_shape=True, cache=cache, overlap=overlap,
-                   schema_version=sv)
+                   fixed_shape=True, cache=cache, schema_version=sv)
 
     @classmethod
     def from_gnn_shared(cls, two_cfg, params, merged, app_name: str,
                         entries: Dict[str, Sequence], *,
                         chunk_size: int = 512, cache: bool = True,
-                        devices: int = 1,
-                        overlap: Optional[bool] = None
-                        ) -> "SurrogateEngine":
+                        devices: int = 1) -> "SurrogateEngine":
         """Per-app view of the cross-app unified surrogate.
 
         ``merged`` is the `repro.core.dataset.MergedDataset` the shared
@@ -1236,8 +1225,8 @@ class SurrogateEngine:
         view featurizes configs with the app's own `ConfigFeaturizer` at
         the merged pad width, appends the app-identity one-hot block, and
         denormalizes with the app's y stats — so five scenarios are
-        served off one set of trained parameters. ``devices``/``overlap``
-        behave exactly as in `from_gnn` (pipelined backend, leading-axis
+        served off one set of trained parameters. ``devices`` behaves
+        exactly as in `from_gnn` (pipelined backend, leading-axis
         sharding).
         """
         from repro.accel import apps as apps_lib
@@ -1281,15 +1270,13 @@ class SurrogateEngine:
         pb = PipelinedBackend(prepare, dispatch, collect,
                               check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend="jax-shared", chunk_size=chunk_size,
-                   fixed_shape=True, cache=cache, overlap=overlap,
+                   fixed_shape=True, cache=cache,
                    schema_version=feat.schema.version)
 
     @classmethod
     def from_gnn_ensemble(cls, ens, ds, app, entries: Dict[str, Sequence],
                           *, chunk_size: int = 512, cache: bool = True,
-                          devices: int = 1,
-                          overlap: Optional[bool] = None
-                          ) -> "SurrogateEngine":
+                          devices: int = 1) -> "SurrogateEngine":
         """Ensemble-GNN engine: objectives = denormalized ensemble MEAN,
         plus a per-objective ensemble-std uncertainty block (columns
         [obj_cols:]) for the DSE acquisition path.
@@ -1299,9 +1286,9 @@ class SurrogateEngine:
         — the Pallas gnn_mp dispatch stays single-model for now). The std
         is denormalized with the same per-target scale as the mean; the
         ssim flip (1 - ssim) leaves its std unchanged. ``devices`` spreads
-        chunks over devices as in `from_gnn`;
-        ``overlap`` pipelines featurization exactly as in `from_gnn` —
-        dispatch enqueues every member group before collect blocks.
+        chunks over devices as in `from_gnn`; featurization is pipelined
+        as in `from_gnn`, and dispatch enqueues every member group before
+        collect blocks.
         """
         import jax
         import jax.numpy as jnp
@@ -1348,7 +1335,7 @@ class SurrogateEngine:
                               check=_guard_check(feat), devices=n_dev)
         return cls(pb, backend="gnn-ensemble", chunk_size=chunk_size,
                    fixed_shape=True, cache=cache, obj_cols=n_obj,
-                   overlap=overlap, schema_version=feat.schema.version)
+                   schema_version=feat.schema.version)
 
     @classmethod
     def from_rforest(cls, rf_models: Dict[int, "object"], ds, app,

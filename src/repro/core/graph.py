@@ -116,9 +116,9 @@ class FeatureSchema:
 
         These columns are what makes featurization host work worth
         pipelining: under schema v2 every cold engine chunk pays a
-        timing sweep + two-scale functional probe, which the engine's
-        overlap mode (`SurrogateEngine`, ``overlap=True``) runs on a
-        prefetch thread while the previous chunk executes on device."""
+        timing sweep + two-scale functional probe, which the engine
+        (`SurrogateEngine`, on a multi-chunk call) runs on a prefetch
+        thread while the previous chunk executes on device."""
         return tuple(f for f in self.block("timing").fields
                      if f != "on_critical_path")
 

@@ -46,9 +46,9 @@ bit-identical across host device counts. Fleets containing the
 sequential ``tpe``/``random`` state machines fall back to the scalar
 path (same results, schedule-independent).
 
-Exposed as `run_islands(...)`, as ``dse.SAMPLERS["islands"]`` (the
-scalar oracle as ``SAMPLERS["islands_ref"]``), and as
-``PipelineConfig(sampler="islands")``.
+Exposed as `run_islands(...)`, as ``dse.SAMPLERS["islands"]`` and as
+``PipelineConfig(sampler="islands")``; tests call `run_islands_ref`
+directly.
 """
 from __future__ import annotations
 
@@ -68,9 +68,9 @@ from repro.core.dse import (Config, DSEResult, EvalFn, SearchCheckpoint,
                             non_dominated_sort, pareto_front, tpe_propose)
 
 # the classic mixed fleet (island i runs DEFAULT_SAMPLERS[i % 4]); pass as
-# `samplers=` explicitly — the default fleet is homogeneous nsga3 cones,
-# which dominates the mixed fleet on merged hypervolume at equal budget
-# (see BENCH_dse.json)
+# `samplers=` explicitly — the default fleet is homogeneous nsga3 cones
+# (tests/test_dse_parallel.py holds such a fleet to at least serial
+# nsga3's hypervolume at equal budget)
 DEFAULT_SAMPLERS: Tuple[str, ...] = ("nsga3", "nsga2", "tpe", "random")
 
 
@@ -86,9 +86,8 @@ class IslandConfig:
                     "random". ``None`` (default) means a homogeneous
                     ``("nsga3",) * n_islands`` fleet — with
                     ``partition_refs`` this is cone-separated parallel
-                    NSGA-III, the strongest configuration measured
-                    (BENCH_dse.json). Fleets containing "tpe"/"random"
-                    run on the scalar path.
+                    NSGA-III. Fleets containing "tpe"/"random" run on
+                    the scalar path.
         epochs:     migration rounds: the generation budget is split into
                     this many epochs, with migration (and a history
                     entry) at each epoch boundary.
@@ -535,8 +534,6 @@ def _epoch_boundary(islands, names, migration, migrate_k, hv_ref, gen,
         if migration == "broadcast":
             # global elite broadcast: every island receives the
             # top-migrate_k scalarized members of the MERGED front.
-            # Measured strictly stronger than ring-neighbour elites on
-            # the library-proxy spaces (BENCH_dse.json).
             sl = np.argsort(_scalarize(po), kind="stable")[:migrate_k]
             mx, mf = [pc[j] for j in sl], po[sl]
             for isl in islands:
@@ -709,9 +706,9 @@ def islands_steps(sizes: Sequence[int], evaluate: EvalFn, budget: int,
 
     # incremental per-island archive snapshots: converting every island's
     # whole tuple archive per checkpoint is O(evaluated); only the rows
-    # added since the last checkpoint are converted and appended (gated
-    # <= 5% overhead in benchmarks/dse_bench). The cached arrays are
-    # never mutated in place, so the sink gets them without a copy.
+    # added since the last checkpoint are converted and appended. The
+    # cached arrays are never mutated in place, so the sink gets them
+    # without a copy.
     ck_arch: Dict[int, Dict] = {}
 
     def _arch_snapshot(i: int, isl):
@@ -874,9 +871,9 @@ def library_proxy_evaluator(app, entries: Dict[str, Sequence]) -> EvalFn:
     matmul against a precomputed path-incidence matrix. Only the oracle's
     deterministic jitter and the SSIM functional model are dropped, so the
     landscape keeps the critical-path plateau structure of the real
-    problem. ~Free per config: search-layer benchmarks and tests
-    (benchmarks/dse_bench.py, tests/test_dse_parallel.py) measure the
-    sampler rather than the surrogate.
+    problem. ~Free per config: search-layer tests
+    (tests/test_dse_parallel.py) exercise the sampler rather than the
+    surrogate.
     """
     import networkx as nx
 

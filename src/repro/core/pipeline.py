@@ -65,7 +65,7 @@ class PipelineConfig:
     dse_budget: int = 2000
     dse_pop: int = 64
     sampler: str = "nsga3"          # nsga3 | nsga2 | tpe | random |
-                                    # islands | islands_ref
+                                    # islands
     dse_islands: int = 4            # island count for sampler="islands"
     dse_migrate_k: int = 4          # merged-front elites broadcast per epoch
     seed: int = 0
@@ -75,13 +75,10 @@ class PipelineConfig:
     eval_devices: int = 1           # shard engine chunks over up to N
                                     # local devices (0 = all); results
                                     # are bit-identical at any width
-    eval_overlap: bool = True       # overlap host featurization with
-                                    # device compute on multi-chunk waves
     use_kernel: str = "auto"        # Pallas gnn_mp: auto | on | off
     ensemble_members: int = 0       # >0: vmapped GNN ensemble + uncertainty
     ensemble_archs: Optional[Tuple[str, ...]] = None  # per-member archs
     early_stop_patience: int = 0    # >0: early stopping on a val split
-    train_backend: str = "scan"     # scan | loop (reference)
     artifact_dir: Optional[str] = None  # on-disk artifact cache root
     dse_checkpoint_every: int = 0   # >0: checkpoint the search every N
                                     # generations into the store; a rerun
@@ -170,17 +167,16 @@ def _train_spec(cfg: PipelineConfig) -> Dict:
             "seed": cfg.seed, "use_critical_path": cfg.use_critical_path,
             "ensemble_members": cfg.ensemble_members,
             "ensemble_archs": cfg.ensemble_archs,
-            "early_stop_patience": cfg.early_stop_patience,
-            "train_backend": cfg.train_backend}
+            "early_stop_patience": cfg.early_stop_patience}
 
 
 def _engine_spec(cfg: PipelineConfig) -> Dict:
-    # eval_devices / eval_overlap are deliberately EXCLUDED (like
-    # dse_checkpoint_every from the search spec): sharded and overlapped
-    # engines are bit-identical to the single-device serial one, so all
-    # widths share one cache slot. Consequence: a memory-cached engine is
-    # NOT reconfigured by changing only those knobs — evict the engine
-    # key (or use a fresh store) to rebuild at a different width.
+    # eval_devices is deliberately EXCLUDED (like dse_checkpoint_every
+    # from the search spec): sharded engines are bit-identical to the
+    # single-device one, so all widths share one cache slot. Consequence:
+    # a memory-cached engine is NOT reconfigured by changing only that
+    # knob — evict the engine key (or use a fresh store) to rebuild at a
+    # different width.
     return {"train": _train_spec(cfg), "eval_chunk": cfg.eval_chunk,
             "use_kernel": cfg.use_kernel}
 
@@ -279,7 +275,6 @@ def stage_train(cfg: PipelineConfig, store: ArtifactStore,
         tr, te = ds.split(0.9)
         if cfg.surrogate == "gnn":
             tc = training.TrainConfig(epochs=cfg.epochs, seed=cfg.seed,
-                                      backend=cfg.train_backend,
                                       patience=cfg.early_stop_patience)
             if cfg.ensemble_members > 0:
                 ens, _hist = training.fit_ensemble(
@@ -326,14 +321,12 @@ def stage_engine(cfg: PipelineConfig, store: ArtifactStore,
         if art.ens is not None:
             return SurrogateEngine.from_gnn_ensemble(
                 art.ens, ds, ctx.app, ctx.entries,
-                chunk_size=cfg.eval_chunk, devices=cfg.eval_devices,
-                overlap=cfg.eval_overlap)
+                chunk_size=cfg.eval_chunk, devices=cfg.eval_devices)
         return SurrogateEngine.from_gnn(art.two_cfg, art.params, ds,
                                         ctx.app, ctx.entries,
                                         chunk_size=cfg.eval_chunk,
                                         use_kernel=cfg.use_kernel,
-                                        devices=cfg.eval_devices,
-                                        overlap=cfg.eval_overlap)
+                                        devices=cfg.eval_devices)
 
     key = store.key("engine", _engine_spec(cfg))
     return store.get_or_build("engine", key, build, memory_only=True)
@@ -371,7 +364,7 @@ def stage_search(cfg: PipelineConfig, store: ArtifactStore,
     def build() -> dse.DSEResult:
         sizes = [len(ctx.entries[n.kind]) for n in ctx.app.unit_nodes]
         sampler = dse.SAMPLERS[cfg.sampler]
-        if cfg.sampler in ("islands", "islands_ref"):
+        if cfg.sampler == "islands":
             # dse_pop is the *global* population; islands split it evenly
             res = sampler(sizes, engine, cfg.dse_budget, seed=cfg.seed,
                           n_islands=cfg.dse_islands,
@@ -540,7 +533,6 @@ def unified_surrogate(apps: Sequence[str], cfg: PipelineConfig,
         schema_version=getattr(
             datasets[next(iter(apps))], "schema_version", 1))
     tc = training.TrainConfig(epochs=cfg.epochs, seed=cfg.seed,
-                              backend=cfg.train_backend,
                               patience=cfg.early_stop_patience)
     n_pad = max(d.x.shape[1] for d in datasets.values())
 
@@ -561,8 +553,7 @@ def unified_surrogate(apps: Sequence[str], cfg: PipelineConfig,
                       "n_layers": cfg.n_layers, "epochs": cfg.epochs,
                       "seed": cfg.seed,
                       "use_critical_path": cfg.use_critical_path,
-                      "early_stop_patience": cfg.early_stop_patience,
-                      "train_backend": cfg.train_backend}}
+                      "early_stop_patience": cfg.early_stop_patience}}
     t0 = time.time()
     fit = store.get_or_build("train_unified",
                              store.key("train_unified", spec), build)
@@ -575,8 +566,8 @@ def unified_surrogate(apps: Sequence[str], cfg: PipelineConfig,
     t0 = time.time()
     engines = {a: SurrogateEngine.from_gnn_shared(
         two_cfg, fit["params"], merged, a, ctxs[a].entries,
-        chunk_size=cfg.eval_chunk, devices=cfg.eval_devices,
-        overlap=cfg.eval_overlap) for a in apps}
+        chunk_size=cfg.eval_chunk, devices=cfg.eval_devices)
+        for a in apps}
     t["engines"] = time.time() - t0
     return UnifiedResult(two_cfg, fit["params"], merged, fit["metrics"],
                          engines, t)

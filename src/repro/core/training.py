@@ -7,7 +7,7 @@ schedule.
 
 Three layers, all sharing one step function (`_make_step`):
 
-``fit_two_stage(..., backend="scan")``
+``fit_two_stage``
     The production path: ONE jitted `lax.scan` over (epochs x steps) with
     a donated (params, opt) carry — zero per-epoch Python dispatch.
     Dropout is live (per-step PRNG keys threaded through `models.losses`
@@ -15,20 +15,21 @@ Three layers, all sharing one step function (`_make_step`):
     weight 0) instead of silently dropped; optional early stopping tracks
     a best-params snapshot against a held-out split inside the scan.
 
-``fit_two_stage(..., backend="loop")``
+``fit_two_stage_loop``
     The per-epoch Python loop kept as the reference implementation: same
-    batch plan, same key derivation, so scanned-vs-loop parity is exact
-    (asserted in tests/test_training.py) — including at dropout > 0,
-    because per-step dropout keys are derived by `fold_in(key, global
-    step)` in both backends.
+    preamble, same batch plan, same key derivation, so scanned-vs-loop
+    parity is exact (asserted in tests/test_training.py) — including at
+    dropout > 0, because per-step dropout keys are derived by
+    `fold_in(key, global step)` in both.
 
 ``fit_ensemble``
     `jax.vmap` of the whole scanned training run over a member axis
     (stacked init params + per-member batch/dropout key streams), so an
-    8-member ensemble trains as one XLA program (benchmarks/train_bench.py
-    gates >= 5x wall-clock vs 8 sequential loop-backend fits). Members may
-    span different GNN architectures: members are grouped per arch (param
-    pytrees differ between archs) and each group trains under one vmap.
+    8-member ensemble trains as one XLA program, each member
+    bit-compatible with its own `fit_two_stage` run
+    (tests/test_training.py). Members may span different GNN
+    architectures: members are grouped per arch (param pytrees differ
+    between archs) and each group trains under one vmap.
     Ensemble mean/std feed `engine.SurrogateEngine.from_gnn_ensemble` as
     the DSE uncertainty column.
 
@@ -57,7 +58,6 @@ class TrainConfig:
     batch_size: int = 64
     epochs: int = 40
     seed: int = 0
-    backend: str = "scan"        # scan | loop
     patience: int = 0            # >0 enables early stopping on a val split
     val_frac: float = 0.1        # held-out fraction when patience > 0
     min_delta: float = 0.0       # required val-loss improvement
@@ -155,7 +155,8 @@ def _shard_members(tree, n_members: int):
 
 def _plan_for(tc: TrainConfig, n: int, bs: int):
     """(idx, w, dropout_key) for one training run, derived from tc.seed
-    the same way in both backends (and per member in `fit_ensemble`)."""
+    the same way in the scan and the loop (and per member in
+    `fit_ensemble`)."""
     pkey, dkey = jax.random.split(jax.random.PRNGKey(tc.seed + 1))
     idx, w = _batch_plan(pkey, n, bs, tc.epochs)
     return idx, w, dkey
@@ -311,22 +312,10 @@ def _build_scan_fit(cfg: models.TwoStageConfig, tc: TrainConfig, data,
     return fit
 
 
-def fit_two_stage(cfg: models.TwoStageConfig, ds_train: AccelDataset,
-                  tc: TrainConfig = TrainConfig(),
-                  log_every: int = 0, return_history: bool = False,
-                  ds_val: Optional[AccelDataset] = None,
-                  params0: Optional[models.TwoStageParams] = None):
-    """Train the two-stage model; returns params (and FitHistory if asked).
-
-    `backend="scan"` runs one jitted lax.scan over (epochs x steps) with a
-    donated carry; `backend="loop"` is the per-epoch reference loop. With
-    `tc.patience > 0`, a validation split (`ds_val`, or `tc.val_frac`
-    carved off the tail of `ds_train`) drives early stopping and the
-    best-val params snapshot is returned.
-
-    ``params0`` warm-starts from existing parameters (numpy leaves are
-    re-deviced) — the fine-tune leg of `evaluate_transfer` and cached
-    cross-app params from the artifact store both enter here."""
+def _fit_data(ds_train: AccelDataset, tc: TrainConfig,
+              ds_val: Optional[AccelDataset]):
+    """(data, n, val_data) for every fit, with the validation split carved
+    off the tail of `ds_train` when early stopping asks for one."""
     n_total = ds_train.y.shape[0]
     val_data = None
     if tc.patience > 0:
@@ -337,43 +326,68 @@ def fit_two_stage(cfg: models.TwoStageConfig, ds_train: AccelDataset,
     data = _as_data(ds_train)
     if tc.data_parallel:
         data = _shard_data(data)
-    n = ds_train.y.shape[0]
+    return data, ds_train.y.shape[0], val_data
 
+
+def _params0(cfg: models.TwoStageConfig, tc: TrainConfig,
+             params0: Optional[models.TwoStageParams]):
     if params0 is None:
-        params0 = models.init(jax.random.PRNGKey(tc.seed), cfg)
-    else:
-        params0 = jax.tree.map(jnp.asarray, params0)
+        return models.init(jax.random.PRNGKey(tc.seed), cfg)
+    return jax.tree.map(jnp.asarray, params0)
 
-    if tc.backend == "scan":
-        idx, w, dkey = _plan_for(tc, n, min(tc.batch_size, n))
-        fit = jax.jit(_build_scan_fit(cfg, tc, data, n, val_data),
-                      donate_argnums=(0,))
-        params, (tr_loss, vls, act) = fit(params0, idx, w, dkey)
-    elif tc.backend == "loop":
-        params, (tr_loss, vls, act) = _fit_loop(cfg, tc, data, n, val_data,
-                                                params0, log_every)
-    else:
-        raise ValueError(f"unknown backend {tc.backend!r}")
+
+def _fit_result(params, tr_loss, vls, act, val_data, return_history):
+    if return_history:
+        return params, FitHistory(
+            train_loss=np.asarray(tr_loss),
+            val_loss=np.asarray(vls) if val_data is not None else None,
+            epochs_run=int(np.asarray(act).sum()))
+    return params
+
+
+def fit_two_stage(cfg: models.TwoStageConfig, ds_train: AccelDataset,
+                  tc: TrainConfig = TrainConfig(),
+                  log_every: int = 0, return_history: bool = False,
+                  ds_val: Optional[AccelDataset] = None,
+                  params0: Optional[models.TwoStageParams] = None):
+    """Train the two-stage model; returns params (and FitHistory if asked).
+
+    Runs one jitted lax.scan over (epochs x steps) with a donated carry
+    (`fit_two_stage_loop` is its per-epoch reference). With
+    `tc.patience > 0`, a validation split (`ds_val`, or `tc.val_frac`
+    carved off the tail of `ds_train`) drives early stopping and the
+    best-val params snapshot is returned.
+
+    ``params0`` warm-starts from existing parameters (numpy leaves are
+    re-deviced) — the fine-tune leg of `evaluate_transfer` and cached
+    cross-app params from the artifact store both enter here."""
+    data, n, val_data = _fit_data(ds_train, tc, ds_val)
+    params0 = _params0(cfg, tc, params0)
+    idx, w, dkey = _plan_for(tc, n, min(tc.batch_size, n))
+    fit = jax.jit(_build_scan_fit(cfg, tc, data, n, val_data),
+                  donate_argnums=(0,))
+    params, (tr_loss, vls, act) = fit(params0, idx, w, dkey)
 
     tr_loss = np.asarray(tr_loss)
     act = np.asarray(act)
-    if log_every and tc.backend == "scan":
+    if log_every:
         for ep in range(tc.epochs):
             if act[ep] and (ep + 1) % log_every == 0:
                 print(f"  epoch {ep + 1}/{tc.epochs} "
                       f"loss={float(np.nanmean(tr_loss[ep])):.4f}")
-    if return_history:
-        hist = FitHistory(
-            train_loss=tr_loss,
-            val_loss=np.asarray(vls) if val_data is not None else None,
-            epochs_run=int(act.sum()))
-        return params, hist
-    return params
+    return _fit_result(params, tr_loss, vls, act, val_data, return_history)
 
 
-def _fit_loop(cfg, tc, data, n, val_data, params0, log_every):
-    """Reference per-epoch Python loop (same batch plan + key streams as
-    the scanned backend, so the two are parity-testable)."""
+def fit_two_stage_loop(cfg: models.TwoStageConfig, ds_train: AccelDataset,
+                       tc: TrainConfig = TrainConfig(),
+                       log_every: int = 0, return_history: bool = False,
+                       ds_val: Optional[AccelDataset] = None,
+                       params0: Optional[models.TwoStageParams] = None):
+    """Reference for `fit_two_stage`: the same arguments and result,
+    trained by a per-epoch Python loop over one jitted epoch, with the
+    same batch plan and key streams, so the two are parity-testable."""
+    data, n, val_data = _fit_data(ds_train, tc, ds_val)
+    params0 = _params0(cfg, tc, params0)
     bs = min(tc.batch_size, n)
     steps = -(-n // bs)
     use_do = cfg.gnn.dropout > 0
@@ -397,13 +411,11 @@ def _fit_loop(cfg, tc, data, n, val_data, params0, log_every):
 
     params, opt = params0, _adam_init(params0)
     best, best_val, bad = params, float("inf"), 0
-    tr_hist, val_hist, act_hist = [], [], []
-    epochs_run = tc.epochs
+    tr_hist, val_hist = [], []
     for ep in range(tc.epochs):
         g_e = jnp.arange(ep * steps, (ep + 1) * steps, dtype=jnp.int32)
         params, opt, losses = epoch(params, opt, idx[ep], w[ep], g_e)
         tr_hist.append(np.asarray(losses))
-        act_hist.append(True)
         if val_data is not None and tc.patience > 0:
             vl = float(val_loss_of(params))
             val_hist.append(vl)
@@ -412,7 +424,6 @@ def _fit_loop(cfg, tc, data, n, val_data, params0, log_every):
             else:
                 bad += 1
             if bad >= tc.patience:
-                epochs_run = ep + 1
                 break
         else:
             val_hist.append(float("nan"))
@@ -428,7 +439,7 @@ def _fit_loop(cfg, tc, data, n, val_data, params0, log_every):
     act = np.concatenate([np.ones(len(tr_hist), bool),
                           np.zeros(pad_eps, bool)])
     out = best if (val_data is not None and tc.patience > 0) else params
-    return out, (tr, vl_arr, act)
+    return _fit_result(out, tr, vl_arr, act, val_data, return_history)
 
 
 # --------------------------------------------------------------------------
@@ -458,17 +469,7 @@ def fit_ensemble(cfg: models.TwoStageConfig, ds_train: AccelDataset,
     if len(member_arch) != n_members:
         raise ValueError("len(archs) must equal n_members")
 
-    n_total = ds_train.y.shape[0]
-    val_data = None
-    if tc.patience > 0:
-        if ds_val is None:
-            n_tr = max(int(n_total * (1.0 - tc.val_frac)), 1)
-            ds_train, ds_val = ds_train.split((n_tr + 0.5) / n_total)
-        val_data = _as_data(ds_val)
-    data = _as_data(ds_train)
-    if tc.data_parallel:
-        data = _shard_data(data)
-    n = ds_train.y.shape[0]
+    data, n, val_data = _fit_data(ds_train, tc, ds_val)
 
     groups: List[Tuple[models.TwoStageConfig, models.TwoStageParams]] = []
     hist_tr, hist_eps = [], []

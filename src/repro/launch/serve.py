@@ -173,8 +173,8 @@ class EvalService:
                       submit/drain queues (one batcher thread per
                       engine) so concurrent requests batch together.
                       ``False`` = serial per-request handling — each
-                      handler calls the engine directly; used as the
-                      benchmark baseline (benchmarks/serve_bench.py).
+                      handler calls the engine directly; the same
+                      responses as coalesced serving (tests/test_serve.py).
         max_workers:  request handler threads (concurrency, not a cap on
                       admissions — see ``max_inflight``).
         drain_wait_s: how long an idle batcher blocks waiting for the
@@ -289,12 +289,12 @@ class EvalService:
         reuses the disk-tier dataset/params and the memory-tier engine —
         and is therefore served bit-identically to `run_staged`.
 
-        ``cfg.eval_devices`` / ``cfg.eval_overlap`` flow through
-        `stage_engine` to the tenant's engine, so drain waves coalesced
-        from many concurrent clients shard across the host's devices and
-        overlap featurization with device compute (bit-identical either
-        way — see docs/serving.md "Sharding and overlap"). Note the
-        engine cache key deliberately ignores those knobs: a tenant
+        ``cfg.eval_devices`` flows through `stage_engine` to the tenant's
+        engine, so drain waves coalesced from many concurrent clients
+        shard across the host's devices, and multi-chunk waves overlap
+        featurization with device compute (bit-identical either way — see
+        docs/serving.md "Sharding and overlap"). Note the engine cache key
+        deliberately ignores the device width: a tenant
         warm-started on a store that already carries the engine keeps the
         cached engine's width (evict the ``engine-*`` key to rebuild)."""
         from repro.core import pipeline as P

@@ -6,7 +6,7 @@ labeling engine:
     float tolerance, *identical* critical-node bit vectors;
   * the config-batched LUT functional model (`apps.accuracy_ssim_batch`)
     vs the closure-based `apps.accuracy_ssim`, across all five apps;
-  * `dataset.build(label_backend="batched")` vs the scalar "loop" path —
+  * `dataset.build` vs its scalar reference `dataset.build_loop` —
     unchanged labels end to end.
 """
 import jax.numpy as jnp
@@ -296,7 +296,7 @@ def test_dataset_build_labels_unchanged(name):
     bit-identical critical labels, float-tolerance PPA/SSIM."""
     kw = dict(n_samples=25, seed=4, n_images=2, img_size=32)
     d_b = ds_lib.build(name, **kw)
-    d_l = ds_lib.build(name, label_backend="loop", **kw)
+    d_l = ds_lib.build_loop(name, **kw)
     assert d_b.configs == d_l.configs
     np.testing.assert_array_equal(d_b.crit, d_l.crit)
     np.testing.assert_allclose(d_b.y_raw[:, :3], d_l.y_raw[:, :3],
@@ -306,12 +306,6 @@ def test_dataset_build_labels_unchanged(name):
     np.testing.assert_array_equal(d_b.adj, d_l.adj)
     np.testing.assert_array_equal(d_b.mask, d_l.mask)
     np.testing.assert_array_equal(d_b.unit_mask, d_l.unit_mask)
-
-
-def test_build_rejects_unknown_backend():
-    with pytest.raises(ValueError, match="label_backend"):
-        ds_lib.build("sobel", n_samples=4, n_images=2, img_size=32,
-                     label_backend="nope")
 
 
 def test_oracle_engine_serves_batched_labels(imgset):

@@ -85,9 +85,12 @@ def _configs(n, width=4, hi=9, seed=0):
 
 def test_overlap_rows_bit_identical_to_serial():
     cfgs = _configs(40)
-    on = SurrogateEngine(_fake_pipeline(), chunk_size=8)
-    off = SurrogateEngine(_fake_pipeline(), chunk_size=8, overlap=False)
-    assert on.overlap and not off.overlap
+    pb = _fake_pipeline()
+    on = SurrogateEngine(pb, chunk_size=8)
+    # the serial reference: the composed call is a plain callable, not a
+    # PipelinedBackend, so the engine runs it chunk by chunk
+    off = SurrogateEngine(lambda c: pb(c), chunk_size=8)
+    assert on._pipeline is pb and off._pipeline is None
     r_on, r_off = on(cfgs), off(cfgs)
     np.testing.assert_array_equal(r_on, r_off)
     np.testing.assert_array_equal(r_on, _rows_for(cfgs))
@@ -163,9 +166,8 @@ def test_overlap_prepare_failure_propagates_like_serial():
 
     pb = PipelinedBackend(bad_prepare, lambda x: x, _rows_for)
     cfgs = _configs(32)
-    for overlap in (True, False):
-        eng = SurrogateEngine(pb, chunk_size=8, overlap=overlap,
-                              nan_guard=False)
+    for backend in (pb, lambda c: pb(c)):
+        eng = SurrogateEngine(backend, chunk_size=8, nan_guard=False)
         with pytest.raises(ValueError, match="bad feature table"):
             eng(cfgs)
 

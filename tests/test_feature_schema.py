@@ -160,16 +160,15 @@ def test_build_vs_hot_path_bit_identical(app_name):
 
 
 def test_build_batched_vs_loop_features_identical():
-    """The loop backend featurizes via scalar `static_timing`; the
-    batched backend via `timing_batch` — the normalized tensors must
-    stay bit-identical (same discipline as the PPA/crit label parity in
+    """`build_loop` featurizes via scalar `static_timing`; `build` via
+    `timing_batch` — the normalized tensors must stay bit-identical (same
+    discipline as the PPA/crit label parity in
     tests/test_batch_oracle.py, now including the dynamic block). The
     probe columns are the one exception: the scalar functional model and
     the vmapped LUT path reduce the SSIM moments in different orders, so
     they carry a float32-noise tolerance instead."""
-    b = ds_lib.build("sobel", n_samples=16, seed=5,
-                     label_backend="batched")
-    l = ds_lib.build("sobel", n_samples=16, seed=5, label_backend="loop")
+    b = ds_lib.build("sobel", n_samples=16, seed=5)
+    l = ds_lib.build_loop("sobel", n_samples=16, seed=5)
     assert b.configs == l.configs
     s = b.schema
     probe_cols = [s.col("timing", f) for f in apps_lib.PROBE_FIELDS]
@@ -200,27 +199,6 @@ def test_probe_batch_matches_scalar():
     # exact design: SSIM == 1 -> distortion 0 (float32 noise only)
     for f in apps_lib.PROBE_FIELDS:
         assert abs(rep[f][-1]) < 1e-6
-
-
-def test_dynamic_off_featurizer_differs():
-    """`dynamic=False` (the bench's static baseline) must actually skip
-    the timing block — guard against the knob silently doing nothing."""
-    ds = ds_lib.build("sobel", n_samples=12, seed=2)
-    app = apps_lib.APPS["sobel"]
-    entries = _entries(app)
-    dyn = ds_lib.featurizer_for(ds, app, entries)
-    stat = ds_lib.ConfigFeaturizer(ds.graph, app, entries, ds.x.shape[1],
-                                   schema=ds.schema, dynamic=False)
-    stat.set_norm(ds.x_mean, ds.x_std)
-    Xd = dyn.normalized(ds.configs[:6])
-    Xs = stat.normalized(ds.configs[:6])
-    sl = ds.schema.dynamic_slice
-    assert not (Xd[:, :, sl] == Xs[:, :, sl]).all()
-    # outside the dynamic block the two agree exactly
-    Xd2, Xs2 = Xd.copy(), Xs.copy()
-    Xd2[:, :, sl] = 0
-    Xs2[:, :, sl] = 0
-    assert (Xd2 == Xs2).all()
 
 
 def test_merge_rejects_mixed_schema_versions():

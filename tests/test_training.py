@@ -130,9 +130,8 @@ def test_scan_loop_parity(small_ds, dropout):
     cfg = _cfg(ds, dropout)
     p_s, h_s = training.fit_two_stage(
         cfg, tr, training.TrainConfig(**TC), return_history=True)
-    p_l, h_l = training.fit_two_stage(
-        cfg, tr, training.TrainConfig(**TC, backend="loop"),
-        return_history=True)
+    p_l, h_l = training.fit_two_stage_loop(
+        cfg, tr, training.TrainConfig(**TC), return_history=True)
     np.testing.assert_allclose(h_s.train_loss, h_l.train_loss, atol=1e-6)
     _leaves_close(p_s, p_l, atol=1e-6)
 
@@ -217,9 +216,8 @@ def test_early_stopping_scan_loop_agree(small_ds):
               lr=5e-2)
     p_s, h_s = training.fit_two_stage(
         cfg, tr, training.TrainConfig(**kw), return_history=True)
-    p_l, h_l = training.fit_two_stage(
-        cfg, tr, training.TrainConfig(**kw, backend="loop"),
-        return_history=True)
+    p_l, h_l = training.fit_two_stage_loop(
+        cfg, tr, training.TrainConfig(**kw), return_history=True)
     assert h_s.epochs_run == h_l.epochs_run
     # rtol absorbs backend fusion-order noise, which compounds over the
     # high-lr epochs (grew past atol=1e-6 alone with the wider v2 features)
