@@ -363,7 +363,8 @@ class PipelinedBackend:
     split exists so `SurrogateEngine._eval_chunked` can overlap the
     phases across chunks:
 
-    * ``prepare(configs, stats=None) -> X`` — host-side featurization
+    * ``prepare(configs, stats=None) -> X`` — host-side featurization of
+      a chunk, given as one ``(B, units)`` int64 array
       (NumPy table lookup plus, under schema v2, the batched timing sweep
       and the tiny-image functional probe; the GNN constructors leave the
       probe's result on the device, so their ``X`` is the host features
@@ -634,7 +635,11 @@ class SurrogateEngine:
                      deferred until every chunk is in flight.
                      Bit-identical to the serial path, which a plain
                      callable and single-chunk calls take
-                     (tests/test_engine_sharded.py).
+                     (tests/test_engine_sharded.py). A `PipelinedBackend`
+                     (its ``prepare`` and its composed call) receives
+                     each chunk as one ``(B, units)`` int64 array; a
+                     plain callable receives a list of tuples of Python
+                     ints.
         backend:     label for stats/reporting ("jax", "pallas", ...).
         chunk_size:  maximum configs per backend call. ``None`` disables
                      chunking entirely — the whole miss list goes to the
@@ -647,7 +652,12 @@ class SurrogateEngine:
                      numpy random forest).
         cache:       memoize results by config key across calls. Assumes a
                      deterministic backend (true for all evaluators here);
-                     disable for stochastic evaluators.
+                     disable for stochastic evaluators. A key is the
+                     bytes of the config's int64 row, led by
+                     ``schema_version`` when there is one; the memo maps
+                     it to a row of one float64 row store, so a call's
+                     output is one gather. Duplicates within a call are
+                     evaluated once either way.
         max_cache:   cache entry bound; oldest entries evicted beyond it.
         obj_cols:    when the backend returns extra per-config columns
                      beyond the objectives (the ensemble backend appends a
@@ -709,7 +719,11 @@ class SurrogateEngine:
         self.nan_guard = nan_guard
         self.nan_retries = int(nan_retries)
         self.quarantined: set = set()
-        self._cache: Dict[Config, np.ndarray] = {}
+        # the memo: key -> slot, in insertion order; slot s's row is
+        # self._rows[s - self._base] (`_store`)
+        self._slots: Dict[bytes, int] = {}
+        self._rows: Optional[np.ndarray] = None
+        self._base = self._next = 0
         self.stats = EngineStats(devices=self.devices)
         # one engine may serve several concurrent samplers (the island
         # orchestrator, repro.core.islands); the lock keeps cache/stats
@@ -724,7 +738,8 @@ class SurrogateEngine:
     # -- public API --------------------------------------------------------
 
     def __call__(self, configs: Sequence[Config]) -> np.ndarray:
-        """Evaluate a batch of configs; rows align with the input order.
+        """Evaluate a batch of configs (a sequence of integer tuples or an
+        ``(n, units)`` integer array); rows align with the input order.
 
         Thread-safe: concurrent callers are serialized on an internal
         lock (results are deterministic regardless of arrival order)."""
@@ -759,44 +774,83 @@ class SurrogateEngine:
         return out[:, :self.obj_cols], out[:, self.obj_cols:]
 
     def _call_locked(self, configs: Sequence[Config]) -> np.ndarray:
+        C = np.ascontiguousarray(configs, np.int64)
+        n = len(C)
         st = self.stats
-        st.update(calls=1, configs=len(configs))
-        st.bump_max(max_batch=len(configs))
+        st.update(calls=1, configs=n)
+        st.bump_max(max_batch=n)
         call = st.calls
         with st.span("engine.call", "wall_time_s", call=call,
-                     configs=len(configs)) as call_span:
+                     configs=n) as call_span:
             with st.span("engine.memo", "memo_s", call=call):
-                raw = [tuple(int(v) for v in c) for c in configs]
-                sv = self.schema_version
-                keys = raw if sv is None else [(sv,) + k for k in raw]
-                miss: List[Config] = []       # raw configs for the backend
-                miss_keys: List[Config] = []  # their (prefixed) memo keys
-                seen = set()
-                for k, r in zip(keys, raw):
-                    if k not in self._cache and k not in seen:
-                        seen.add(k)
-                        miss.append(r)
-                        miss_keys.append(k)
+                first: Dict[bytes, int] = {}
+                # each row's first position in the call, and the call's
+                # distinct keys in input order
+                pos = np.fromiter(map(first.setdefault, self._keys(C),
+                                      range(n)), np.int64, n)
+                uniq = np.fromiter(first.values(), np.int64, len(first))
+                slot = np.fromiter(map(self._slots.get, first,
+                                       itertools.repeat(-1)),
+                                   np.int64, len(first))
+                fresh = slot < 0
+                miss = C[uniq[fresh]]
             call_span.set_metadata(misses=len(miss))
-            st.update(cache_hits=len(keys) - len(miss))
-            rows = []
-            if miss:
+            st.update(cache_hits=n - len(miss))
+            if len(miss):
                 t0 = time.perf_counter()
                 rows = self._eval_chunked(miss, call)
                 st.update(eval_time_s=time.perf_counter() - t0,
                           evaluated=len(miss))
             with st.span("engine.assemble", "memo_s", call=call):
-                for k, r in zip(miss_keys, rows):
-                    self._cache[k] = r
-                out = np.stack([self._cache[k] for k in keys],
-                               0).astype(np.float64)
+                if len(miss):
+                    start = self._store(rows)
+                    self._slots.update(zip(
+                        itertools.compress(first, fresh.tolist()),
+                        range(start, start + len(miss))))
+                    slot[fresh] = np.arange(start, start + len(miss))
+                at = np.empty(n, np.int64)
+                at[uniq] = slot - self._base
+                out = np.take(self._rows, at[pos], axis=0)
                 if not self.cache_enabled:
-                    self._cache.clear()
-                elif len(self._cache) > self.max_cache:
-                    drop = len(self._cache) - self.max_cache
-                    for k in list(itertools.islice(self._cache, drop)):
-                        del self._cache[k]
+                    self._clear()
+                elif len(self._slots) > self.max_cache:
+                    drop = len(self._slots) - self.max_cache
+                    for k in list(itertools.islice(self._slots, drop)):
+                        del self._slots[k]
         return out
+
+    def _keys(self, C: np.ndarray) -> List[bytes]:
+        """One memo key per row of the ``(n, units)`` int64 array: the
+        row's bytes, led by the schema version when the engine has one."""
+        if self.schema_version is not None:
+            K = np.empty((C.shape[0], C.shape[1] + 1), np.int64)
+            K[:, 0] = self.schema_version
+            K[:, 1:] = C
+            C = K
+        return C.view(np.dtype((np.void, C.shape[1] * 8))).ravel().tolist()
+
+    def _store(self, y: np.ndarray) -> int:
+        """Write rows ``y`` to the row store at the next free slots and
+        return the first. Slots are numbered in insertion order, and FIFO
+        eviction drops the oldest keys, so the live slots are always the
+        ``len(self._slots)`` below ``self._next``; store row ``i`` holds
+        slot ``self._base + i``. A full store is copied, its live rows
+        first, into one of twice the rows it must hold."""
+        start, m = self._next, len(y)
+        if self._rows is None or start + m - self._base > len(self._rows):
+            live = len(self._slots)
+            rows = np.empty((2 * (live + m),) + y.shape[1:], np.float64)
+            if live:
+                rows[:live] = self._rows[start - live - self._base:
+                                         start - self._base]
+            self._rows, self._base = rows, start - live
+        self._rows[start - self._base:start - self._base + m] = y
+        self._next = start + m
+        return start
+
+    def _clear(self) -> None:
+        self._slots.clear()
+        self._rows, self._base, self._next = None, 0, 0
 
     def reset_stats(self) -> None:
         """Zero the counters (cache contents and the engine's device
@@ -807,11 +861,11 @@ class SurrogateEngine:
     def clear_cache(self) -> None:
         """Drop all memoized results."""
         with self._lock:
-            self._cache.clear()
+            self._clear()
 
     @property
     def cache_size(self) -> int:
-        return len(self._cache)
+        return len(self._slots)
 
     # -- cross-request batching queue --------------------------------------
     #
@@ -937,16 +991,20 @@ class SurrogateEngine:
             b <<= 1
         return min(b, self.chunk_size)
 
-    def _eval_backend(self, chunk: List[Config]) -> np.ndarray:
-        """One backend call, re-issued under `self.retry` on transient
-        faults (`stats.retries` counts every re-issue)."""
+    def _eval_backend(self, chunk: np.ndarray) -> np.ndarray:
+        """One backend call on an int64 chunk, in the backend's form (the
+        array for a `PipelinedBackend`, a list of tuples of ints for a
+        plain callable), re-issued under `self.retry` on transient faults
+        (`stats.retries` counts every re-issue)."""
+        batch = chunk if self._pipeline is not None \
+            else list(map(tuple, chunk.tolist()))
         if self.retry is None:
-            return np.asarray(self._batch_fn(chunk))
+            return np.asarray(self._batch_fn(batch))
         return np.asarray(self.retry.call(
-            self._batch_fn, chunk,
+            self._batch_fn, batch,
             on_retry=lambda e: self.stats.update(retries=1)))
 
-    def _guard_rows(self, part: List[Config], y: np.ndarray) -> np.ndarray:
+    def _guard_rows(self, part: np.ndarray, y: np.ndarray) -> np.ndarray:
         """Non-finite-row guard: heal corrupted rows by re-evaluating the
         offending configs individually; quarantine persistent offenders.
 
@@ -964,25 +1022,25 @@ class SurrogateEngine:
         for j in bad:
             healed = False
             for _ in range(self.nan_retries):
-                row = self._eval_backend([part[j]])[0]
+                row = self._eval_backend(part[j:j + 1])[0]
                 if np.all(np.isfinite(row)):
                     y[j] = row
                     healed = True
                     break
             if not healed:
                 y[j] = np.inf
-                self.quarantined.add(part[j])
+                self.quarantined.add(tuple(part[j].tolist()))
                 self.stats.update(quarantined=1)
         return y
 
-    def _plan_chunks(self, configs: List[Config]
-                     ) -> List[Tuple[int, int, List[Config]]]:
-        """Split the miss list into ``(start, take, padded_chunk)`` work
-        items. ``chunk_size=None`` plans the whole list as one chunk (the
-        explicit no-chunking mode `queued_view` uses); fixed-shape
-        padding up to the power-of-two bucket is applied and counted
-        here."""
-        plan: List[Tuple[int, int, List[Config]]] = []
+    def _plan_chunks(self, configs: np.ndarray
+                     ) -> List[Tuple[int, int, np.ndarray]]:
+        """Split the ``(n, units)`` miss array into ``(start, take,
+        padded_chunk)`` work items. ``chunk_size=None`` plans the whole
+        array as one chunk (the explicit no-chunking mode `queued_view`
+        uses); fixed-shape padding up to the power-of-two bucket repeats
+        the chunk's last row and is counted here."""
+        plan: List[Tuple[int, int, np.ndarray]] = []
         i, n = 0, len(configs)
         size = n if self.chunk_size is None else self.chunk_size
         while i < n:
@@ -991,7 +1049,8 @@ class SurrogateEngine:
             if self.fixed_shape and take < self.chunk_size:
                 b = self._bucket(take)
                 self.stats.update(padded=b - take)
-                chunk = chunk + [chunk[-1]] * (b - take)
+                chunk = np.concatenate(
+                    [chunk, np.repeat(chunk[-1:], b - take, 0)])
             plan.append((i, take, chunk))
             i += take
         return plan
@@ -1015,8 +1074,7 @@ class SurrogateEngine:
                 f"(stats.padded_fraction tracks the running rate)",
                 RuntimeWarning, stacklevel=4)
 
-    def _eval_chunked(self, configs: List[Config],
-                      call: int) -> np.ndarray:
+    def _eval_chunked(self, configs: np.ndarray, call: int) -> np.ndarray:
         plan = self._plan_chunks(configs)
         self._warn_padding(plan, len(configs))
         if self._pipeline is not None and len(plan) >= 2:
@@ -1039,8 +1097,8 @@ class SurrogateEngine:
             self.stats.update(chunks=1)
         return np.concatenate(rows, 0)
 
-    def _eval_pipelined(self, plan: List[Tuple[int, int, List[Config]]],
-                        configs: List[Config], call: int) -> np.ndarray:
+    def _eval_pipelined(self, plan: List[Tuple[int, int, np.ndarray]],
+                        configs: np.ndarray, call: int) -> np.ndarray:
         """Two-stage pipelined execution of the chunk plan (the LM decode
         idiom): ONE worker thread runs the backend's host ``prepare``
         (featurization: table lookup + timing sweep + functional probe)

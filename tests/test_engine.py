@@ -1,9 +1,13 @@
 """SurrogateEngine: chunking/padding, memo cache, featurizer and
 Pallas-kernel-path parity, DSE integration."""
+import itertools
+import zlib
+
 import numpy as np
 import pytest
 
-from repro.core.engine import SurrogateEngine, _ConfigFeaturizer
+from repro.core.engine import (PipelinedBackend, SurrogateEngine,
+                               _ConfigFeaturizer)
 
 
 # --------------------------------------------------------------------------
@@ -101,6 +105,223 @@ def test_backend_row_count_mismatch_raises():
     eng = SurrogateEngine(lambda cfgs: _toy_rows(cfgs)[:-1], chunk_size=8)
     with pytest.raises(ValueError):
         eng(_rand_configs(4, seed=7))
+
+
+# --------------------------------------------------------------------------
+# the memo against the per-configuration loop it replaced
+# --------------------------------------------------------------------------
+
+class _LoopMemo:
+    """The memo as a Python loop per configuration: tuple keys, a set to
+    dedupe, one dict row per key and an ``np.stack`` of them. The
+    engine's array memo must give its rows and counts exactly."""
+
+    def __init__(self, batch_fn, *, chunk_size=512, fixed_shape=False,
+                 cache=True, max_cache=1_000_000, schema_version=None):
+        self._batch_fn = batch_fn
+        self.chunk_size, self.fixed_shape = chunk_size, fixed_shape
+        self.cache_enabled, self.max_cache = cache, max_cache
+        self.schema_version = schema_version
+        self._cache = {}
+        self.cache_hits = self.evaluated = self.padded = 0
+
+    def __call__(self, configs):
+        raw = [tuple(int(v) for v in c) for c in configs]
+        sv = self.schema_version
+        keys = raw if sv is None else [(sv,) + k for k in raw]
+        miss = []       # raw configs for the backend
+        miss_keys = []  # their (prefixed) memo keys
+        seen = set()
+        for k, r in zip(keys, raw):
+            if k not in self._cache and k not in seen:
+                seen.add(k)
+                miss.append(r)
+                miss_keys.append(k)
+        self.cache_hits += len(keys) - len(miss)
+        rows = []
+        if miss:
+            rows = self._eval_chunked(miss)
+            self.evaluated += len(miss)
+        for k, r in zip(miss_keys, rows):
+            self._cache[k] = r
+        out = np.stack([self._cache[k] for k in keys], 0).astype(np.float64)
+        if not self.cache_enabled:
+            self._cache.clear()
+        elif len(self._cache) > self.max_cache:
+            drop = len(self._cache) - self.max_cache
+            for k in list(itertools.islice(self._cache, drop)):
+                del self._cache[k]
+        return out
+
+    def _eval_chunked(self, configs):
+        rows, i, n = [], 0, len(configs)
+        while i < n:
+            take = min(self.chunk_size, n - i)
+            chunk = configs[i:i + take]
+            if self.fixed_shape and take < self.chunk_size:
+                b = 1
+                while b < take:
+                    b <<= 1
+                b = min(b, self.chunk_size)
+                self.padded += b - take
+                chunk = chunk + [chunk[-1]] * (b - take)
+            rows.append(np.asarray(self._batch_fn(chunk))[:take])
+            i += take
+        return np.concatenate(rows, 0)
+
+    @property
+    def cache_size(self):
+        return len(self._cache)
+
+
+class _RecordingBackend:
+    """float32 rows with every mantissa bit in play, and the chunks it
+    was handed, as tuples of ints."""
+
+    def __init__(self):
+        self.chunks = []
+
+    def __call__(self, configs):
+        self.chunks.append([tuple(int(v) for v in c) for c in configs])
+        a = np.asarray(configs, np.float64)
+        w = np.arange(1, a.shape[1] + 1)
+        return np.stack([np.sin(a @ w), np.sqrt(a.sum(1) + 0.5),
+                         (a * a).sum(1) / 7.0], 1).astype(np.float32)
+
+
+def _memo_calls(case):
+    """``(engine kwargs, input form, calls)`` of a memo case: each call an
+    ``(n, units)`` int array, handed to the engine in the input form."""
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    if case in ("units5_array", "units16_tuples"):
+        units = 5 if case == "units5_array" else 16
+        a = rng.integers(0, 3, (40, units))
+        b = rng.integers(0, 3, (30, units))
+        calls = [a, a[rng.permutation(40)], np.concatenate([b, a[:20]])]
+        kw = dict(chunk_size=16, fixed_shape=True, schema_version=2)
+        return kw, ("array" if units == 5 else "tuples"), calls
+    if case == "duplicates":
+        c = rng.integers(0, 9, (6, 5))
+        calls = [np.concatenate([c[[0] * 10], c, c[::-1], c[[2] * 3]]),
+                 np.concatenate([c[[4] * 5], rng.integers(0, 9, (3, 5))])]
+        return dict(chunk_size=4, fixed_shape=True), "array", calls
+    if case == "permuted_hits":
+        a = rng.integers(0, 9, (50, 5))
+        calls = [a, a[rng.permutation(50)],
+                 np.concatenate([a[rng.permutation(50)[:25]],
+                                 rng.integers(0, 9, (5, 5))])[::-1]]
+        return dict(chunk_size=64), "tuples", calls
+    if case == "no_cache":
+        a = rng.integers(0, 4, (30, 5))
+        calls = [a, a[::-1], np.concatenate([a[:5], a[:5]])]
+        return dict(chunk_size=8, fixed_shape=True, cache=False), \
+            "array", calls
+    if case == "eviction":
+        a = rng.integers(0, 20, (40, 5))
+        calls = [a[:8], a[4:12], a[10:22], a[:16], a[20:24], a[:40:3],
+                 a[::-1]]
+        return dict(chunk_size=4, max_cache=10, schema_version=1), \
+            "array", calls
+    if case == "ragged_chunk":
+        a = rng.integers(0, 1000, (37, 16))
+        calls = [a, np.concatenate([a[::2], rng.integers(0, 1000, (3, 16))])]
+        return dict(chunk_size=16, fixed_shape=True), "tuples", calls
+    raise KeyError(case)
+
+
+@pytest.mark.parametrize("case", [
+    "units5_array", "units16_tuples", "duplicates", "permuted_hits",
+    "no_cache", "eviction", "ragged_chunk"])
+def test_memo_matches_the_per_config_loop(case):
+    kw, form, calls = _memo_calls(case)
+    be, ref_be = _RecordingBackend(), _RecordingBackend()
+    eng, ref = SurrogateEngine(be, **kw), _LoopMemo(ref_be, **kw)
+    for C in calls:
+        C = np.asarray(C, np.int64)
+        arg = C if form == "array" else [tuple(r) for r in C.tolist()]
+        y, want = eng(arg), ref(arg)
+        assert y.dtype == want.dtype == np.float64
+        assert np.array_equal(y, want)
+        assert eng.stats.cache_hits == ref.cache_hits
+        assert eng.stats.evaluated == ref.evaluated
+        assert eng.stats.padded == ref.padded
+        assert eng.cache_size == ref.cache_size
+    assert be.chunks == ref_be.chunks
+    if case == "eviction":
+        assert ref.cache_size == 10 and ref.cache_hits > 0
+    if case == "no_cache":
+        assert eng.cache_size == 0
+
+
+def test_backends_receive_their_own_form():
+    """A plain callable gets a list of tuples of Python ints; a
+    `PipelinedBackend`'s ``prepare`` gets the padded chunk as one int64
+    array, on the pipelined path and in the composed serial call."""
+    cfgs = [tuple(int(v) for v in r)
+            for r in np.random.default_rng(8).integers(0, 50, (11, 5))]
+    assert len(set(cfgs)) == 11
+    got = []
+
+    def plain(configs):
+        got.append(configs)
+        return _toy_rows(configs)
+
+    SurrogateEngine(plain, chunk_size=8, fixed_shape=True)(np.array(cfgs))
+    assert [len(b) for b in got] == [8, 4]
+    for b in got:
+        assert type(b) is list
+        assert all(type(c) is tuple and len(c) == 5 for c in b)
+        assert all(type(v) is int for c in b for v in c)
+    assert got[0] + got[1][:3] == cfgs and got[1][3] == cfgs[-1]
+
+    prepared = []
+
+    def prepare(configs, stats=None):
+        prepared.append(configs)
+        return configs
+
+    pb = PipelinedBackend(prepare, lambda X: X, _toy_rows)
+    eng = SurrogateEngine(pb, chunk_size=8, fixed_shape=True)
+    np.testing.assert_array_equal(eng(cfgs), _toy_rows(cfgs))   # 2 chunks
+    np.testing.assert_array_equal(eng(cfgs[:3] + [(1, 2, 3, 4, 5)]),
+                                  _toy_rows(cfgs[:3] + [(1, 2, 3, 4, 5)]))
+    assert [c.shape for c in prepared] == [(8, 5), (4, 5), (1, 5)]
+    for c in prepared:
+        assert type(c) is np.ndarray and c.dtype == np.int64
+    np.testing.assert_array_equal(prepared[1], np.array(cfgs[8:] + cfgs[-1:]))
+
+
+@pytest.mark.parametrize("pipelined", [False, True],
+                         ids=["plain", "pipelined"])
+def test_persistent_nonfinite_config_quarantined_as_tuple(pipelined):
+    poison = (3, 1, 4, 1, 5)
+    retried = []
+
+    def rows(configs):
+        y = _toy_rows(configs)
+        y[[tuple(int(v) for v in c) == poison for c in configs]] = np.nan
+        return y
+
+    def prepare(configs, stats=None):
+        if len(configs) == 1:
+            retried.append(configs)
+        return configs
+
+    be = PipelinedBackend(prepare, lambda X: X, rows) if pipelined else rows
+    eng = SurrogateEngine(be, chunk_size=4, nan_retries=2)
+    cfgs = np.array(_rand_configs(9, seed=11) + [poison])
+    y = eng(cfgs)
+    assert eng.quarantined == {poison}
+    (q,) = eng.quarantined
+    assert all(type(v) is int for v in q)
+    assert eng.stats.quarantined == 1
+    assert np.all(y[-1] == np.inf)
+    np.testing.assert_array_equal(y[:-1], _toy_rows(cfgs[:-1]))
+    if pipelined:       # each single-config retry in the backend's form
+        assert len(retried) == 2
+        for c in retried:
+            assert c.dtype == np.int64 and c.shape == (1, 5)
+            assert tuple(c[0].tolist()) == poison
 
 
 # --------------------------------------------------------------------------
